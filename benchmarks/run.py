@@ -261,6 +261,8 @@ def main() -> None:
                     help="relative regression tolerance for --check "
                          "(default 0.10)")
     args = ap.parse_args()
+    from repro.launch import compile_cache
+    compile_cache.configure()
     if args.check:
         only = set(args.only.split(",")) if args.only else None
         if check_against(args.check, tol=args.check_tol, only=only):

@@ -16,13 +16,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-# jax.shard_map only became a top-level alias in newer releases; fall back
-# to the experimental home on the versions that predate it
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:                                                  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def _axes_tuple(ax):
     if ax is None:
@@ -61,7 +54,7 @@ def append_kv(cache_leaf, delta_leaf, pos, spec: P, minfo, axis: int = 3):
     delta_spec[axis] = None
     fn = functools.partial(_append_local, seq_axes=seq_axes,
                            mesh_axis_sizes=minfo.axis_sizes, axis=axis)
-    return _shard_map(
+    return jax.shard_map(
         fn, mesh=minfo.mesh,
         in_specs=(spec, P(*delta_spec), P()),
         out_specs=spec,

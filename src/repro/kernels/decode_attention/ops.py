@@ -50,6 +50,8 @@ def decode_attention(
                                q.shape[0] * k_cache.shape[2],
                                q.shape[1] // k_cache.shape[2], q.shape[2],
                                k_cache.shape[1])
+    if interpret is None:
+        interpret = _on_cpu()
     return _decode_attention(q, k_cache, v_cache, pos, window=window,
                              logit_cap=logit_cap, block_k=block_k,
                              interpret=interpret)
@@ -67,10 +69,8 @@ def _decode_attention(
     window: Optional[int],
     logit_cap: Optional[float],
     block_k: int,
-    interpret: Optional[bool],
+    interpret: bool,
 ) -> jax.Array:
-    if interpret is None:
-        interpret = _on_cpu()
     B, H, hd = q.shape
     _, S, KV, _ = k_cache.shape
     G = H // KV
@@ -110,6 +110,8 @@ def decode_attention_kvmajor(
                                q.shape[0] * k_cache.shape[1],
                                q.shape[1] // k_cache.shape[1], q.shape[2],
                                k_cache.shape[2])
+    if interpret is None:
+        interpret = _on_cpu()
     return _decode_attention_kvmajor(q, k_cache, v_cache, pos, window=window,
                                      logit_cap=logit_cap, block_k=block_k,
                                      interpret=interpret)
@@ -126,10 +128,8 @@ def _decode_attention_kvmajor(
     window,
     logit_cap,
     block_k: int,
-    interpret,
+    interpret: bool,
 ):
-    if interpret is None:
-        interpret = _on_cpu()
     B, H, hd = q.shape
     _, KV, S, _ = k_cache.shape
     G = H // KV
@@ -182,6 +182,8 @@ def paged_decode_attention(
     different sequence positions share one batch, and a freed slot
     (``kv_lens[b] == 0``) returns zeros.  Validated against
     ``ref.decode_attention_ref_ragged``."""
+    if interpret is None:
+        interpret = _on_cpu()
     return _paged_decode_attention(q, k_pages, v_pages, kv_lens,
                                    block_tables, window=window,
                                    logit_cap=logit_cap, interpret=interpret)
@@ -198,10 +200,8 @@ def _paged_decode_attention(
     *,
     window: Optional[int],
     logit_cap: Optional[float],
-    interpret: Optional[bool],
+    interpret: bool,
 ) -> jax.Array:
-    if interpret is None:
-        interpret = _on_cpu()
     B, H, hd = q.shape
     P, psz, KV, _ = k_pages.shape
     G = H // KV

@@ -25,22 +25,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-
-    def _compiler_params():
-        try:
-            return pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary"))
-        except Exception:
-            return None
-except Exception:  # pragma: no cover
-    _VMEM = None
-
-    def _compiler_params():
-        return None
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"))
 
 NEG_INF = -2.0 ** 30
 
@@ -119,40 +107,35 @@ def paged_decode_attention_fwd(
     kernel = functools.partial(_paged_kernel, page_size=page_size, ns=ns,
                                window=window, logit_cap=logit_cap,
                                scale=scale)
-    if _VMEM is not None:
-        scratch = [
-            _VMEM((G, 128), jnp.float32),
-            _VMEM((G, 128), jnp.float32),
-            _VMEM((G, hd), jnp.float32),
-        ]
-        # the index_map consults the prefetched block table: grid step
-        # (b, j) DMAs physical page tbl[b, j].  Entries past a slot's
-        # length are skipped by pl.when but still indexed — the wrapper
-        # clamps them into range.
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(BKV, ns),
-            in_specs=[
-                pl.BlockSpec((1, G, hd),
-                             lambda b, j, lens_ref, tbl_ref: (b, 0, 0)),
-                pl.BlockSpec((1, page_size, hd),
-                             lambda b, j, lens_ref, tbl_ref:
-                             (tbl_ref[b, j], 0, 0)),
-                pl.BlockSpec((1, page_size, hd),
-                             lambda b, j, lens_ref, tbl_ref:
-                             (tbl_ref[b, j], 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, G, hd),
-                                   lambda b, j, lens_ref, tbl_ref: (b, 0, 0)),
-            scratch_shapes=scratch,
-        )
-        cp = _compiler_params()
-        kwargs = {"compiler_params": cp} if cp is not None else {}
-        return pl.pallas_call(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((BKV, G, hd), q.dtype),
-            interpret=interpret,
-            **kwargs,
-        )(kv_lens, block_tables, q, k_pages, v_pages)
-    raise RuntimeError("pallas tpu backend unavailable")  # pragma: no cover
+    # the index_map consults the prefetched block table: grid step (b, j)
+    # DMAs physical page tbl[b, j].  Entries past a slot's length are
+    # skipped by pl.when but still indexed — the wrapper clamps them into
+    # range.
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(BKV, ns),
+        in_specs=[
+            pl.BlockSpec((1, G, hd),
+                         lambda b, j, lens_ref, tbl_ref: (b, 0, 0)),
+            pl.BlockSpec((1, page_size, hd),
+                         lambda b, j, lens_ref, tbl_ref:
+                         (tbl_ref[b, j], 0, 0)),
+            pl.BlockSpec((1, page_size, hd),
+                         lambda b, j, lens_ref, tbl_ref:
+                         (tbl_ref[b, j], 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, G, hd),
+                               lambda b, j, lens_ref, tbl_ref: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((G, 128), jnp.float32),
+            pltpu.VMEM((G, 128), jnp.float32),
+            pltpu.VMEM((G, hd), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((BKV, G, hd), q.dtype),
+        interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
+    )(kv_lens, block_tables, q, k_pages, v_pages)

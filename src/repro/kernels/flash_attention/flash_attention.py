@@ -18,23 +18,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU compiler params are optional (ignored in interpret mode)
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-
-    def _compiler_params():
-        try:
-            return pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary"))
-        except Exception:
-            return None
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
-
-    def _compiler_params():
-        return None
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 NEG_INF = -2.0 ** 30
 
@@ -134,20 +121,11 @@ def flash_attention_fwd(
         window=window, logit_cap=logit_cap, q_offset=q_offset, scale=scale,
         groups=G, kv_len=kv_len)
 
-    if _VMEM is not None:
-        scratch = [
-            _VMEM((rows, 128), jnp.float32),
-            _VMEM((rows, 128), jnp.float32),
-            _VMEM((rows, hd), jnp.float32),
-        ]
-    else:  # pragma: no cover
-        scratch = [
-            pl.MemorySpace.ANY((rows, 128), jnp.float32),  # type: ignore
-        ]
-
-    cp = _compiler_params()
-    kwargs = {"compiler_params": cp} if cp is not None else {}
-
+    scratch = [
+        pltpu.VMEM((rows, 128), jnp.float32),
+        pltpu.VMEM((rows, 128), jnp.float32),
+        pltpu.VMEM((rows, hd), jnp.float32),
+    ]
     return pl.pallas_call(
         kernel,
         grid=(BKV, nq, nk),
@@ -160,5 +138,5 @@ def flash_attention_fwd(
         out_shape=jax.ShapeDtypeStruct((BKV, G, Tq, hd), q.dtype),
         scratch_shapes=scratch,
         interpret=interpret,
-        **kwargs,
+        compiler_params=_COMPILER_PARAMS,
     )(q, k, v)

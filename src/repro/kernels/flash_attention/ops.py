@@ -53,6 +53,8 @@ def flash_attention(
             block_q = cfg["block_q"] if cfg else DEFAULT_BLOCK_Q
         if block_k is None:
             block_k = cfg["block_k"] if cfg else DEFAULT_BLOCK_K
+    if interpret is None:
+        interpret = _on_cpu()
     return _flash_attention(q, k, v, causal=causal, window=window,
                             logit_cap=logit_cap, q_offset=q_offset,
                             block_q=block_q, block_k=block_k,
@@ -74,10 +76,8 @@ def _flash_attention(
     q_offset: int,
     block_q: int,
     block_k: int,
-    interpret: Optional[bool],
+    interpret: bool,
 ) -> jax.Array:
-    if interpret is None:
-        interpret = _on_cpu()
     B, Tq, H, hd = q.shape
     _, Tk, KV, _ = k.shape
     G = H // KV

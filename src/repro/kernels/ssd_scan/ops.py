@@ -49,6 +49,8 @@ def ssd_scan(
                               P=x.shape[3], N=Bm.shape[2], T=x.shape[1])
         chunk = _largest_dividing_chunk(
             x.shape[1], cfg["chunk"] if cfg else DEFAULT_CHUNK)
+    if interpret is None:
+        interpret = _on_cpu()
     return _ssd_scan(x, dt, A, Bm, Cm, chunk=chunk, interpret=interpret)
 
 
@@ -61,10 +63,8 @@ def _ssd_scan(
     Cm: jax.Array,
     *,
     chunk: int,
-    interpret=None,
+    interpret: bool,
 ):
-    if interpret is None:
-        interpret = _on_cpu()
     B, T, H, P = x.shape
     chunk = min(chunk, T)
     assert T % chunk == 0, (T, chunk)

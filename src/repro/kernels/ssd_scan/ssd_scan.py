@@ -16,22 +16,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-
-    def _compiler_params():
-        try:
-            return pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary"))
-        except Exception:
-            return None
-except Exception:  # pragma: no cover
-    _VMEM = None
-
-    def _compiler_params():
-        return None
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _kernel(xdt_ref, dA_ref, b_ref, c_ref, y_ref, state_out_ref, s_ref, *,
@@ -46,12 +34,19 @@ def _kernel(xdt_ref, dA_ref, b_ref, c_ref, y_ref, state_out_ref, s_ref, *,
     dA = dA_ref[0, 0].astype(jnp.float32)         # (chunk, 1)  dt*A (log decay)
     Bc = b_ref[0].astype(jnp.float32)             # (chunk, N)
     Cc = c_ref[0].astype(jnp.float32)             # (chunk, N)
+    P = xdt.shape[1]
 
-    cum = jnp.cumsum(dA, axis=0)                  # (chunk, 1)
-    seg = cum - cum.T                             # (chunk, chunk) log decay t<-s
     rows = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     cols = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    L = jnp.where(rows >= cols, jnp.exp(seg), 0.0)
+    causal = rows >= cols
+    # inclusive prefix sum as a matmul with the lower-triangular mask (Mosaic
+    # has no cumsum lowering)
+    cum = lax.dot_general(causal.astype(jnp.float32), dA,
+                          (((1,), (0,)), ((), ())),
+                          precision=lax.Precision.HIGHEST,
+                          preferred_element_type=jnp.float32)  # (chunk, 1)
+    seg = cum - cum.T                             # (chunk, chunk) log decay t<-s
+    L = jnp.where(causal, jnp.exp(seg), 0.0)
 
     scores = lax.dot_general(Cc, Bc, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
@@ -67,10 +62,17 @@ def _kernel(xdt_ref, dA_ref, b_ref, c_ref, y_ref, state_out_ref, s_ref, *,
     y_ref[0, 0] = y.astype(y_ref.dtype)
 
     # state update: S = S * exp(cum[-1]) + xdt^T @ (B * decay_to_end)
-    decay_to_end = jnp.exp(cum[-1:] - cum)        # (chunk, 1)
+    total = cum[chunk - 1:chunk]                  # (1, 1) static slice
+    decay_to_end = jnp.exp(total - cum)           # (chunk, 1)
     S_local = lax.dot_general(xdt, Bc * decay_to_end, (((0,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)  # (P, N)
-    s_ref[...] = state * jnp.exp(cum[-1]) + S_local
+    # the chunk's total log decay, one copy per state row: Mosaic cannot
+    # broadcast a (1, 1) value over sublanes and lanes at once
+    total_rows = lax.dot_general(jnp.ones((P, chunk), jnp.float32), dA,
+                                 (((1,), (0,)), ((), ())),
+                                 precision=lax.Precision.HIGHEST,
+                                 preferred_element_type=jnp.float32)  # (P, 1)
+    s_ref[...] = state * jnp.exp(total_rows) + S_local
 
     @pl.when(ci == nc - 1)
     def _final():
@@ -92,12 +94,6 @@ def ssd_scan_fwd(
     nc = T // chunk
 
     kernel = functools.partial(_kernel, chunk=chunk, nc=nc)
-    if _VMEM is None:  # pragma: no cover
-        raise RuntimeError("pallas tpu backend unavailable")
-    scratch = [_VMEM((P, N), jnp.float32)]
-    cp = _compiler_params()
-    kwargs = {"compiler_params": cp} if cp is not None else {}
-
     y, final_state = pl.pallas_call(
         kernel,
         grid=(B, H, nc),
@@ -115,8 +111,8 @@ def ssd_scan_fwd(
             jax.ShapeDtypeStruct((B, H, T, P), jnp.float32),
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
-        **kwargs,
+        compiler_params=_COMPILER_PARAMS,
     )(xdt, dA, Bm, Cm)
     return y, final_state

@@ -7,15 +7,22 @@ jax device state (device count is locked at first jax init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 from repro.distributed.sharding import MeshInfo
+
+
+def _auto_mesh(shape, axes):
+    # Auto axes: the steps place arrays with with_sharding_constraint, which
+    # refers only to Auto axes (jax.make_mesh defaults to Explicit)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_mesh_info(*, multi_pod: bool = False) -> MeshInfo:
@@ -23,5 +30,5 @@ def make_mesh_info(*, multi_pod: bool = False) -> MeshInfo:
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> MeshInfo:
-    """Small mesh over however many host devices exist (tests)."""
-    return MeshInfo(jax.make_mesh((data, model), ("data", "model")))
+    """Small mesh over the devices of one host."""
+    return MeshInfo(_auto_mesh((data, model), ("data", "model")))

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import argparse
 import os
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -45,12 +46,13 @@ def build_library(estimator: LatencyEstimator, exclude_id: int) -> None:
 
 def make_controller(name: str, executor, slo_s: float, job_id: int = -1,
                     bs: int = 1, mtl: int = 1, *, surface_library=None,
-                    surface_key=None):
+                    surface_key=None, max_bs: int = 128):
     if name in ("dnnscaler", "hybrid"):
         est = LatencyEstimator(max_mtl=10)
         build_library(est, job_id)
         mode = "hybrid" if name == "hybrid" else "auto"
         return DNNScalerController(executor, slo_s, estimator=est, mode=mode,
+                                   max_bs=max_bs,
                                    surface_library=surface_library,
                                    surface_key=surface_key)
     if name == "clipper":
@@ -58,11 +60,11 @@ def make_controller(name: str, executor, slo_s: float, job_id: int = -1,
     return StaticController(bs=bs, mtl=mtl)
 
 
-def real_executor_for(arch: str, tiny: bool) -> tuple:
-    from repro.configs.base import get_config
+def real_executor_for(arch: str, tiny: bool, seed: int = 0) -> tuple:
+    from repro.configs.base import InputShape, get_config
     from repro.models import api
     cfg = get_config(arch, tiny=tiny)
-    rng = jax.random.PRNGKey(0)
+    rng = jax.random.PRNGKey(seed)
     params = api.init_params(rng, cfg)
 
     @jax.jit
@@ -71,14 +73,19 @@ def real_executor_for(arch: str, tiny: bool) -> tuple:
         return loss
 
     def make_batch(n):
-        from repro.configs.base import InputShape
-        shp = InputShape("serve", 128, n, "train")
-        return api.make_batch(rng, cfg, shp)
+        return api.make_batch(rng, cfg, InputShape("serve", 128, n, "train"))
 
-    return RealExecutor(fwd, params, make_batch), cfg
+    # admission is bounded by the device's own memory where the backend
+    # reports it (the executor sizes items from its compiled buckets)
+    stats = jax.devices()[0].memory_stats() or {}
+    return RealExecutor(fwd, params, make_batch,
+                        mem_bytes=stats.get("bytes_limit")), cfg
 
 
-def main() -> None:
+def main(argv=None) -> Optional[dict]:
+    """Runs the launcher on ``argv`` (default ``sys.argv``).  A single-job
+    run returns its summary: label, approach, steady knobs, engine summary
+    and, for a real executor, its exec-cache counters."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--job", type=int, default=None, help="paper job # (1-30)")
     ap.add_argument("--arch", default=None, help="assigned architecture id")
@@ -192,8 +199,10 @@ def main() -> None:
     ap.add_argument("--vectorized", action="store_true",
                     help="use the array-backed VectorClusterEngine "
                          "(bit-identical results, faster at fleet scale)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
+    from repro.launch import compile_cache
+    compile_cache.configure()
     from repro.perf import autotune
     autotune.configure(cache_dir=args.autotune_cache_dir,
                        tune_on_miss=args.autotune or None)
@@ -455,8 +464,10 @@ def main() -> None:
         engine = ServingEngine(SimExecutor(prof, seed=args.seed + 1), slo)
         label = f"job{job.job_id} {prof.name}"
     elif args.arch and args.real:
-        executor, cfg = real_executor_for(args.arch, args.tiny)
+        executor, cfg = real_executor_for(args.arch, args.tiny, args.seed)
         base = executor.mean_latency(1, 1)
+        from repro.serving.token_engine import memory_slot_cap
+        max_bs = memory_slot_cap(executor, 128)
         slo = args.slo_ms / 1e3 if args.slo_ms else base * 4
         lib = surface_key = None
         if store is not None and args.controller in ("dnnscaler", "hybrid"):
@@ -466,12 +477,14 @@ def main() -> None:
             from repro.perf import autotune as _at
             lib = SurfaceLibrary()
             surface_key = f"{cfg.name}/serve"
-            res = store.load_surfaces(lib, device_class="host-cpu",
+            res = store.load_surfaces(lib,
+                                      device_class=executor.device_class,
                                       autotune_generation=_at.generation())
             print(f"profile store: {len(res['loaded'])} surface rows "
                   f"loaded, {len(res['evicted'])} evicted")
         ctrl = make_controller(args.controller, executor, slo,
-                               surface_library=lib, surface_key=surface_key)
+                               surface_library=lib, surface_key=surface_key,
+                               max_bs=max_bs)
         engine = ServingEngine(executor, slo, instance_launch_s=0.2)
         label = f"{cfg.name} (real)"
     else:
@@ -494,27 +507,34 @@ def main() -> None:
     approach = getattr(ctrl, "approach", args.controller)
     print(f"{label}: controller={args.controller} approach={approach} "
           f"steady(bs={act.bs}, mtl={act.mtl})")
+    real = isinstance(executor, RealExecutor)
+    # a real executor's power is assumed, not measured: no power_eff
+    power = "" if real else f"  power_eff {s['power_efficiency']:.2f}/W"
     print(f"  throughput {s['throughput']:.1f}/s  p95 {s['p95_s']*1e3:.1f}ms "
-          f"(SLO {slo*1e3:.1f}ms)  attainment {s['slo_attainment']:.3f}  "
-          f"power_eff {s['power_efficiency']:.2f}/W")
-    if hasattr(executor, "cache_stats"):
+          f"(SLO {slo*1e3:.1f}ms)  attainment {s['slo_attainment']:.3f}"
+          + power)
+    out = {"label": label, "approach": approach, "bs": act.bs,
+           "mtl": act.mtl, "slo_s": slo, "summary": s}
+    if real:
         cs = executor.cache_stats
         print(f"  exec-cache hits {cs.hits} misses {cs.misses} "
               f"(hit rate {cs.hit_rate:.2f})  compile "
               f"{cs.compile_time_s:.2f}s charged "
-              f"{s['compile_stall_s']:.2f}s")
+              f"{s['compile_stall_s']:.2f}s  max_bs {max_bs}")
+        out["cache_stats"] = cs
     if hasattr(ctrl, "probe_count"):
         print(f"  probes: {ctrl.probe_count} distinct (bs, mtl) points")
     if store is not None and getattr(ctrl, "surface_library", None) is not None:
         from repro.perf import autotune as _at
         wrote = store.persist_surface(
             ctrl.surface_library, ctrl.surface_key,
-            signature=ctrl.surface_key, device_class="host-cpu",
+            signature=ctrl.surface_key, device_class=executor.device_class,
             autotune_generation=_at.generation())
         store.save()
         print(f"  profile store: surface row "
               f"{'persisted' if wrote else 'too sparse to persist'} "
               f"({store.path})")
+    return out
 
 
 if __name__ == "__main__":

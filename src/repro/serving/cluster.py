@@ -1972,8 +1972,7 @@ class ClusterEngine:
                         continue
                     ts = np.linspace(t0, t1, 65)
                     ps = np.asarray([self._power_price(t) for t in ts])
-                    trapezoid = getattr(np, "trapezoid", np.trapz)
-                    idle_cost += float(trapezoid(ps, ts)) \
+                    idle_cost += float(np.trapezoid(ps, ts)) \
                         * self.fleet[d].device.idle_w
             power_cost = idle_cost + self._dynamic_cost_usd
         return {

@@ -117,8 +117,7 @@ class OpenLoopQueue:
                            np.float64)
         if np.all(rates == rates[0]):
             return float(rates[0]) * window
-        trapezoid = getattr(np, "trapezoid", None) or np.trapz
-        return float(trapezoid(rates, knots))
+        return float(np.trapezoid(rates, knots))
 
     def step(self, win_start: float, t_end: float, capacity: int,
              arrival_end: Optional[float] = None) -> tuple:

@@ -281,6 +281,10 @@ class RealExecutor:
         self._exec: dict = {}
         self._tile_generation = tile_generation or autotune.generation
         self._param_bytes: Optional[float] = None
+        # bucket items -> bytes beyond the parameters its compiled program
+        # needs (memory analysis; kept only under a `mem_bytes` budget)
+        self._footprints: dict = {}
+        self._raw_act_per_item: Optional[float] = None
         self.cache_stats = ExecCacheStats()
         self._pending_compile = 0.0      # compile seconds not yet charged
         self.partition = None            # TenantSlice: capped-batch proxy
@@ -305,6 +309,17 @@ class RealExecutor:
         return n
 
     @property
+    def device_class(self) -> str:
+        """Profile-store class of the device the parameters live on:
+        ``host-cpu`` on the CPU backend, else the device kind as a slug
+        (``tpu-v5-lite`` for a TPU v5e)."""
+        leaf = jax.tree_util.tree_leaves(self.params)[0]
+        dev = next(iter(leaf.devices()))
+        if dev.platform == "cpu":
+            return "host-cpu"
+        return dev.device_kind.lower().replace(" ", "-")
+
+    @property
     def param_bytes(self) -> float:
         if self._param_bytes is None:    # fits() runs per scaler candidate
             leaves = jax.tree_util.tree_leaves(self.params)
@@ -312,20 +327,35 @@ class RealExecutor:
                                           for x in leaves))
         return self._param_bytes
 
-    def _batch_bytes_per_item(self) -> float:
+    def _act_bytes(self, n_bucket: int) -> float:
+        """Bytes beyond the parameters that a bucket of `n_bucket` items
+        needs.  An explicit `act_bytes_per_item` wins.  Otherwise, once
+        buckets are compiled, the compiler's memory analysis of them: a
+        line through the smallest and largest compiled bucket, or, with one
+        compiled, its bytes per item (an upper bound for larger buckets,
+        since it charges the fixed workspace to every item).  Before any
+        compile, the batch's own bytes times ACT_MULT."""
         if self.act_bytes_per_item is not None:
-            return self.act_bytes_per_item
-        leaves = jax.tree_util.tree_leaves(self.make_batch(1))
-        raw = sum(np.asarray(x).size * np.asarray(x).dtype.itemsize
-                  for x in leaves)
-        self.act_bytes_per_item = raw * ACT_MULT
-        return self.act_bytes_per_item
+            return n_bucket * self.act_bytes_per_item
+        if self._footprints:
+            lo, hi = min(self._footprints), max(self._footprints)
+            f_lo, f_hi = self._footprints[lo], self._footprints[hi]
+            if lo == hi:
+                return n_bucket * f_lo / lo
+            slope = max(0.0, (f_hi - f_lo) / (hi - lo))
+            return f_lo + (n_bucket - lo) * slope
+        if self._raw_act_per_item is None:    # fits() runs per candidate
+            leaves = jax.tree_util.tree_leaves(self.make_batch(1))
+            raw = sum(np.asarray(x).size * np.asarray(x).dtype.itemsize
+                      for x in leaves)
+            self._raw_act_per_item = raw * ACT_MULT
+        return n_bucket * self._raw_act_per_item
 
     def fits(self, bs: int, mtl: int) -> bool:
         """Memory-aware admission when a `mem_bytes` budget is configured
-        (param bytes + per-item activation estimate at the BUCKETED batch,
-        since that is the shape actually compiled); the historical hard
-        cap `bs * mtl <= 4096` when no budget is given.
+        (param bytes + the activation estimate of `_act_bytes` at the
+        BUCKETED batch, since that is the shape actually compiled); the
+        historical hard cap `bs * mtl <= 4096` when no budget is given.
 
         Decode-mode profiles additionally charge the paged KV cache:
         `kv_bytes_per_item` per LIVE slot (not bucketed — pages are
@@ -336,7 +366,7 @@ class RealExecutor:
         if self.mem_bytes is None:
             return n <= 4096
         need = (self.param_bytes * PARAM_OVERHEAD
-                + self.bucket(n) * self._batch_bytes_per_item()
+                + self._act_bytes(self.bucket(n))
                 + n * self.kv_bytes_per_item)
         return need <= self.mem_bytes
 
@@ -364,6 +394,12 @@ class RealExecutor:
             jax.block_until_ready(
                 executable(self.params, self._staged_batch(batch)))
         dt = time.perf_counter() - t0
+        if self.aot and self.mem_bytes is not None:
+            ma = executable.memory_analysis()
+            if ma is not None:
+                self._footprints[n_bucket] = (
+                    ma.temp_size_in_bytes + ma.output_size_in_bytes
+                    + ma.argument_size_in_bytes - self.param_bytes)
         self.cache_stats.compile_time_s += dt
         self._pending_compile += dt
         # tagged with the generation read AFTER compiling — those are the

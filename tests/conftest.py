@@ -4,70 +4,3 @@ import os
 # launch/dryrun.py ONLY.  A couple of distribution tests spawn subprocesses
 # that set their own XLA_FLAGS.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-# ---------------------------------------------------------------------------
-# hypothesis compat shim: when the real package is missing (it is not baked
-# into the container image — `pip install -r requirements-dev.txt` gets the
-# real thing), install a minimal stand-in so the property-test modules still
-# collect and run.  Property tests degrade to fixed-example tests: each
-# strategy contributes its boundary values plus a midpoint, and @given runs
-# the cartesian product of those examples.  This conftest is imported before
-# any test module, so the fake lands in sys.modules in time.
-# ---------------------------------------------------------------------------
-try:
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    import functools
-    import itertools
-    import random
-    import sys
-    import types
-
-    class _Strategy:
-        def __init__(self, examples):
-            self.examples = list(examples)
-
-    def _integers(lo, hi):
-        return _Strategy(sorted({lo, (lo + hi) // 2, hi}))
-
-    def _floats(lo, hi):
-        return _Strategy([lo, (lo + hi) / 2.0, hi])
-
-    def _sampled_from(seq):
-        seq = list(seq)
-        idx = sorted({0, len(seq) // 2, len(seq) - 1})
-        return _Strategy([seq[i] for i in idx])
-
-    def _randoms(use_true_random=False):
-        return _Strategy([random.Random(s) for s in (0, 1, 2)])
-
-    def _given(*strats):
-        def deco(fn):
-            # NOT functools.wraps: pytest would introspect the wrapped
-            # signature (via __wrapped__) and demand fixtures for the
-            # strategy parameters — the wrapper must look zero-arg
-            def run():
-                for ex in itertools.product(*(s.examples for s in strats)):
-                    fn(*ex)
-            run.__name__ = fn.__name__
-            run.__doc__ = fn.__doc__
-            return run
-        return deco
-
-    def _settings(*args, **kw):
-        def deco(fn):
-            return fn
-        return deco
-
-    _st = types.ModuleType("hypothesis.strategies")
-    _st.integers = _integers
-    _st.floats = _floats
-    _st.sampled_from = _sampled_from
-    _st.randoms = _randoms
-    _hyp = types.ModuleType("hypothesis")
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _hyp.strategies = _st
-    _hyp.__is_shim__ = True
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st

@@ -230,6 +230,21 @@ def test_fits_memory_aware():
     assert exl.fits(4097, 2)
 
 
+def test_fits_sizes_items_from_compiled_buckets():
+    """Under a budget and with no per-item figure given, admission reads
+    the compiled buckets' memory analysis: a line through the smallest and
+    largest compiled bucket."""
+    from repro.serving.executor import PARAM_OVERHEAD
+    ex = _tiny_executor(mem_bytes=1e12)
+    ex.warmup(1, 1)
+    ex.warmup(64, 1)
+    f1, f64 = ex._footprints[1], ex._footprints[64]
+    assert f64 > f1 > 0
+    need_128 = ex.param_bytes * PARAM_OVERHEAD + f1 + 127 * (f64 - f1) / 63
+    ex.mem_bytes = need_128 * (1 + 1e-9)
+    assert ex.fits(128, 1) and not ex.fits(129, 1)   # 129 pads to 192
+
+
 # ---------------------------------------------------------------------------
 # Vectorized pricing == scalar pricing; fast tail window == np.quantile.
 # ---------------------------------------------------------------------------

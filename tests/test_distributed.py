@@ -112,12 +112,12 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs.base import get_config, InputShape
-from repro.distributed.sharding import MeshInfo
 from repro.launch import steps as steps_lib
+from repro.launch.mesh import make_host_mesh
 from repro.models import api
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
-minfo = MeshInfo(mesh)
+minfo = make_host_mesh(4, 2)
+mesh = minfo.mesh
 cfg = get_config("smollm_360m", tiny=True).replace(num_heads=4, num_kv_heads=2,
                                                    head_dim=32, d_model=128,
                                                    d_ff=256, vocab_size=512)
@@ -156,12 +156,12 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs.base import get_config, InputShape
-from repro.distributed.sharding import MeshInfo
 from repro.launch import steps as steps_lib
+from repro.launch.mesh import make_host_mesh
 from repro.models import api
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
-minfo = MeshInfo(mesh)
+minfo = make_host_mesh(4, 2)
+mesh = minfo.mesh
 cfg = get_config("mixtral_8x22b", tiny=True)
 B, S = 8, 128
 shape = InputShape("d", S, B, "decode")

@@ -8,9 +8,10 @@ Invariants pinned here:
   * known-bad damping never re-probes a pinned point before the amnesty
     window, and re-probes it after.
 
-With hypothesis installed these run randomized; without it the conftest
-shim degrades them to fixed boundary/midpoint examples.
+The feedback sequences are drawn from a hypothesis-chosen seed.
 """
+
+import random
 
 import numpy as np
 import pytest
@@ -48,8 +49,11 @@ def _scalers(seed_mtl=5):
 # Bounds under arbitrary feedback
 # ---------------------------------------------------------------------------
 @settings(max_examples=25, deadline=None)
-@given(st.randoms(use_true_random=False))
-def test_knobs_stay_in_bounds(rnd):
+@given(st.integers(0, 2**32 - 1))
+def test_knobs_stay_in_bounds(seed):
+    # one seed per example: 1200 draws straight from hypothesis would make
+    # its smallest input too large for the large_base_example health check
+    rnd = random.Random(seed)
     for sc in _scalers():
         for _ in range(300):
             act = sc.action()
@@ -66,8 +70,9 @@ def test_knobs_stay_in_bounds(rnd):
 # ---------------------------------------------------------------------------
 @settings(max_examples=20, deadline=None)
 @given(st.floats(ALPHA * SLO + 1e-6, 0.98 * SLO - 1e-6),
-       st.randoms(use_true_random=False))
-def test_no_movement_inside_band(in_band_p95, rnd):
+       st.integers(0, 2**32 - 1))
+def test_no_movement_inside_band(in_band_p95, seed):
+    rnd = random.Random(seed)
     # the 0.98*SLO upper edge keeps the fed values inside every scaler's
     # band even if HybridScaler's optional safety margin (its band is
     # [alpha*(1-safety)*SLO, (1-safety)*SLO]; safety defaults to 0) is

@@ -1,0 +1,164 @@
+"""Compile the main path's Pallas kernels, and whole model steps, for a
+described TPU v5e chip at published widths.
+
+Nothing runs: the TPU compiler refuses here what interpret mode accepts (an
+unsupported primitive, a misaligned tile, too much VMEM), so these tests
+catch it without a chip.  The topology is described inside a fixture, never
+at import, and all these tests live in this one file: only one process at a
+time may load the TPU library, and it keeps it until it exits.
+"""
+
+import contextlib
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.base import get_config
+from repro.models import api
+
+SMOLLM = get_config("smollm-360m")
+MAMBA2 = get_config("mamba2-1.3b")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@contextlib.contextmanager
+def _no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one: keep the cache off around it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
+def _compile(fn, *args):
+    with _no_persistent_cache():
+        return jax.jit(fn).lower(*args).compile()
+
+
+def _spec(sharding, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_attention_compiles(one_chip):
+    from repro.kernels.flash_attention.ops import flash_attention
+    cfg = SMOLLM
+    B, T = 8, 512
+    q = _spec(one_chip, (B, T, cfg.num_heads, cfg.head_dim))
+    kv = _spec(one_chip, (B, T, cfg.num_kv_heads, cfg.head_dim))
+    _assert_kernel(_compile(
+        lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                        interpret=False), q, kv, kv))
+
+
+def test_decode_attention_kvmajor_compiles(one_chip):
+    from repro.kernels.decode_attention.ops import decode_attention_kvmajor
+    cfg = SMOLLM
+    B, S = 8, 1024
+    q = _spec(one_chip, (B, cfg.num_heads, cfg.head_dim))
+    kv = _spec(one_chip, (B, cfg.num_kv_heads, S, cfg.head_dim))
+    pos = _spec(one_chip, (), jnp.int32)
+    _assert_kernel(_compile(
+        lambda q, k, v, pos: decode_attention_kvmajor(q, k, v, pos,
+                                                      interpret=False),
+        q, kv, kv, pos))
+
+
+@pytest.mark.parametrize("page_size", [16, 64, 128])
+def test_paged_decode_attention_compiles(one_chip, page_size):
+    from repro.kernels.decode_attention.ops import paged_decode_attention
+    cfg = SMOLLM
+    B, S = 8, 1024
+    ns = S // page_size
+    q = _spec(one_chip, (B, cfg.num_heads, cfg.head_dim))
+    pages = _spec(one_chip, (B * ns, page_size, cfg.num_kv_heads,
+                             cfg.head_dim))
+    lens = _spec(one_chip, (B,), jnp.int32)
+    tables = _spec(one_chip, (B, ns), jnp.int32)
+    _assert_kernel(_compile(
+        lambda q, k, v, lens, tbl: paged_decode_attention(
+            q, k, v, lens, tbl, interpret=False),
+        q, pages, pages, lens, tables))
+
+
+def test_ssd_scan_compiles(one_chip):
+    from repro.kernels.ssd_scan.ops import ssd_scan
+    from repro.models.mamba import dims
+    cfg = MAMBA2
+    d_in, H, P, N = dims(cfg)
+    B, T = 2, 512
+    x = _spec(one_chip, (B, T, H, P))
+    dt = _spec(one_chip, (B, T, H), jnp.float32)
+    A = _spec(one_chip, (H,), jnp.float32)
+    bc = _spec(one_chip, (B, T, N))
+    _assert_kernel(_compile(
+        lambda x, dt, A, Bm, Cm: ssd_scan(x, dt, A, Bm, Cm,
+                                          chunk=cfg.ssm_chunk_size,
+                                          interpret=False),
+        x, dt, A, bc, bc))
+
+
+def _on_chip(monkeypatch):
+    """The kernels' wrappers ask the default backend (here the CPU) whether
+    to interpret; answer as the chip would."""
+    from repro.kernels.decode_attention import ops as decode_ops
+    from repro.kernels.flash_attention import ops as flash_ops
+    from repro.kernels.ssd_scan import ops as ssd_ops
+    for ops in (decode_ops, flash_ops, ssd_ops):
+        monkeypatch.setattr(ops, "_on_cpu", lambda: False)
+
+
+def _param_specs(cfg, sharding):
+    return jax.tree.map(lambda s: _spec(sharding, s.shape, s.dtype),
+                        api.param_specs(cfg))
+
+
+def test_smollm_decode_step_compiles_with_kernels(one_chip, monkeypatch):
+    _on_chip(monkeypatch)
+    cfg = SMOLLM.replace(kernel_impl="pallas")
+    B, S = 8, 1024
+    params = _param_specs(cfg, one_chip)
+    cache = jax.tree.map(lambda s: _spec(one_chip, s.shape, s.dtype),
+                         jax.eval_shape(lambda: api.init_cache(cfg, B, S)))
+    tokens = _spec(one_chip, (B,), jnp.int32)
+    pos = _spec(one_chip, (), jnp.int32)
+    _assert_kernel(_compile(
+        lambda p, c, t, pos: api.decode_step(p, c, t, pos, cfg),
+        params, cache, tokens, pos))
+
+
+def test_smollm_prefill_step_compiles_with_kernels(one_chip, monkeypatch):
+    _on_chip(monkeypatch)
+    cfg = SMOLLM.replace(kernel_impl="pallas")
+    B, T = 2, 512
+    params = _param_specs(cfg, one_chip)
+    batch = {"tokens": _spec(one_chip, (B, T), jnp.int32)}
+    _assert_kernel(_compile(
+        lambda p, b: api.prefill(p, b, cfg, 2 * T), params, batch))
