@@ -174,7 +174,8 @@ def make_decode_step(cfg: ModelConfig, minfo: shd.MeshInfo,
         logits, deltas = api.decode_step(params, cache, tokens, pos, cfg,
                                          bspec=bspec, windowed=windowed_cache,
                                          return_deltas=True)
-        new_cache = apply_cache_deltas(cache, deltas, pos, c_specs, minfo)
+        with jax.named_scope("cache_update"):
+            new_cache = apply_cache_deltas(cache, deltas, pos, c_specs, minfo)
         return logits, new_cache
 
     fn = jax.jit(

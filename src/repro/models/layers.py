@@ -32,6 +32,21 @@ def constrain_batch(x: Array, bspec) -> Array:
     return jax.lax.with_sharding_constraint(x, spec)
 
 
+def named_scope(name: str):
+    """Decorator: every op the function stages carries ``name`` in its JAX
+    name stack (the ``op_name`` metadata that a profiler trace reports), so
+    a trace tells which part of the step an op belongs to.  Enters a fresh
+    ``jax.named_scope`` per call, so the function may be traced in several
+    threads at once."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
+
+
 # ---------------------------------------------------------------------------
 # Norms
 # ---------------------------------------------------------------------------
@@ -395,6 +410,7 @@ def qkv_proj(p: dict, x: Array, cfg) -> tuple[Array, Array, Array]:
     return q, k, v
 
 
+@named_scope("attention")
 def attn_block_apply(
     p: dict,
     x: Array,                   # (B, T, d)
@@ -435,10 +451,13 @@ def attn_block_apply(
             # with a DUS, then run the blocked online-softmax kernel.
             from repro.kernels.decode_attention.ops import (
                 decode_attention_kvmajor)
-            kc = lax.dynamic_update_slice_in_dim(
-                cache["k"], k_new.astype(cache["k"].dtype), cache_pos, axis=2)
-            vc = lax.dynamic_update_slice_in_dim(
-                cache["v"], v_new.astype(cache["v"].dtype), cache_pos, axis=2)
+            with jax.named_scope("cache_update"):
+                kc = lax.dynamic_update_slice_in_dim(
+                    cache["k"], k_new.astype(cache["k"].dtype), cache_pos,
+                    axis=2)
+                vc = lax.dynamic_update_slice_in_dim(
+                    cache["v"], v_new.astype(cache["v"].dtype), cache_pos,
+                    axis=2)
             o = decode_attention_kvmajor(q[:, 0], kc, vc, cache_pos,
                                          window=window,
                                          logit_cap=cfg.attn_logit_softcap)
@@ -524,6 +543,7 @@ def init_mlp(rng, cfg, d_ff: Optional[int] = None) -> dict:
     return p
 
 
+@named_scope("mlp")
 def mlp_apply(p: dict, x: Array, cfg) -> Array:
     h = rmsnorm(x, p["norm"], cfg.norm_eps)
     y = (jax.nn.silu(h @ p["wg"]) * (h @ p["wi"])) @ p["wo"]

@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.models.layers import named_scope
+
 Array = jax.Array
 
 
@@ -141,6 +143,7 @@ def ssd_decode_step(state: Array, x: Array, dt: Array, A: Array,
     return y.astype(x.dtype), new_state
 
 
+@named_scope("ssm")
 def mamba_block_apply(p: dict, x: Array, cfg, *, state: Optional[dict] = None,
                       mode: str = "train"):
     """Residual Mamba2 block.

@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.models.layers import named_scope
+
 Array = jax.Array
 
 ROUTE_GROUP = 256  # tokens per routing group (static capacity)
@@ -101,6 +103,7 @@ def moe_apply(p: dict, h: Array, cfg) -> tuple[Array, Array]:
     return y.reshape(B, T, d), aux
 
 
+@named_scope("mlp")
 def moe_block_apply(p: dict, x: Array, cfg) -> tuple[Array, Array]:
     from repro.models.layers import rmsnorm
     h = rmsnorm(x, p["norm"], cfg.norm_eps)

@@ -223,6 +223,7 @@ def run_group_prefill(gp, x, cfg, kind, cache, *, positions, cache_pos=0,
     window = cfg.sliding_window
     T = x.shape[1]
 
+    @L.named_scope("cache_update")
     def put(buf, kv):  # buf (count,B,KV,cap,hd); kv (count,B,T,KV,hd)
         kv = kv.transpose(0, 1, 3, 2, 4)         # -> (count,B,KV,T,hd)
         return lax.dynamic_update_slice_in_dim(buf, kv.astype(buf.dtype),
@@ -234,7 +235,8 @@ def run_group_prefill(gp, x, cfg, kind, cache, *, positions, cache_pos=0,
                                            mode="prefill", positions=positions,
                                            seq_axis=seq_axis)
             return y, (kv["k"], kv["v"], aux)
-        x, (ks, vs, auxs) = lax.scan(body, x, gp)
+        with jax.named_scope("layers"):
+            x, (ks, vs, auxs) = lax.scan(body, x, gp)
         new_cache = {"k": put(cache["k"], ks), "v": put(cache["v"], vs)}
         return x, new_cache, auxs.sum()
 
@@ -247,7 +249,8 @@ def run_group_prefill(gp, x, cfg, kind, cache, *, positions, cache_pos=0,
                                             mode="prefill", positions=positions,
                                             seq_axis=seq_axis)
             return y, (kv_l["k"], kv_l["v"], kv_g["k"], kv_g["v"], a1 + a2)
-        x, (kl, vl, kg, vg, auxs) = lax.scan(body, x, gp)
+        with jax.named_scope("layers"):
+            x, (kl, vl, kg, vg, auxs) = lax.scan(body, x, gp)
         new_cache = {
             "local": {"k": put(cache["local"]["k"], kl),
                       "v": put(cache["local"]["v"], vl)},
@@ -261,7 +264,8 @@ def run_group_prefill(gp, x, cfg, kind, cache, *, positions, cache_pos=0,
             lp, st = inp
             y, new_st = M.mamba_block_apply(lp, carry, cfg, state=st, mode="prefill")
             return y, new_st
-        x, new_states = lax.scan(body, x, (gp, cache))
+        with jax.named_scope("layers"):
+            x, new_states = lax.scan(body, x, (gp, cache))
         return x, new_states, jnp.zeros((), jnp.float32)
 
     if kind == "hybrid_super":
@@ -278,7 +282,9 @@ def run_group_prefill(gp, x, cfg, kind, cache, *, positions, cache_pos=0,
             y, kv, _ = dense_layer_apply(shared, y, cfg, window=window,
                                          mode="prefill", positions=positions)
             return y, (new_mstates, kv["k"], kv["v"])
-        x, (new_m, ks, vs) = lax.scan(body, x, (gp["mamba"], cache["mamba"]))
+        with jax.named_scope("layers"):
+            x, (new_m, ks, vs) = lax.scan(body, x, (gp["mamba"],
+                                                    cache["mamba"]))
         new_cache = {"mamba": new_m, "k": put(cache["k"], ks),
                      "v": put(cache["v"], vs)}
         return x, new_cache, jnp.zeros((), jnp.float32)
@@ -298,6 +304,7 @@ def run_group_decode(gp, x, cfg, kind, cache, *, pos, windowed=False,
     window = cfg.sliding_window
     positions = pos[None] if pos.ndim == 0 else pos
 
+    @L.named_scope("cache_update")
     def put(buf, delta, ring):
         # buf (count,B,KV,cap,hd); delta (count,B,KV,1,hd)
         if return_deltas:
@@ -316,7 +323,8 @@ def run_group_decode(gp, x, cfg, kind, cache, *, pos, windowed=False,
                                          cache_pos=pos, positions=positions,
                                          ring=ring)
             return y, (kv["k"], kv["v"])
-        x, (dk, dv) = lax.scan(body, x, (gp, cache["k"], cache["v"]))
+        with jax.named_scope("layers"):
+            x, (dk, dv) = lax.scan(body, x, (gp, cache["k"], cache["v"]))
         return x, {"k": put(cache["k"], dk, ring), "v": put(cache["v"], dv, ring)}
 
     if kind == "local_global":
@@ -330,9 +338,10 @@ def run_group_decode(gp, x, cfg, kind, cache, *, pos, windowed=False,
                                            mode="decode", kv={"k": kg, "v": vg},
                                            cache_pos=pos, positions=positions)
             return y, (kv_l["k"], kv_l["v"], kv_g["k"], kv_g["v"])
-        x, (dkl, dvl, dkg, dvg) = lax.scan(
-            body, x, (gp, cache["local"]["k"], cache["local"]["v"],
-                      cache["global"]["k"], cache["global"]["v"]))
+        with jax.named_scope("layers"):
+            x, (dkl, dvl, dkg, dvg) = lax.scan(
+                body, x, (gp, cache["local"]["k"], cache["local"]["v"],
+                          cache["global"]["k"], cache["global"]["v"]))
         return x, {
             "local": {"k": put(cache["local"]["k"], dkl, windowed),
                       "v": put(cache["local"]["v"], dvl, windowed)},
@@ -345,7 +354,8 @@ def run_group_decode(gp, x, cfg, kind, cache, *, pos, windowed=False,
             lp, st = inp
             y, new_st = M.mamba_block_apply(lp, carry, cfg, state=st, mode="decode")
             return y, new_st
-        x, new_states = lax.scan(body, x, (gp, cache))
+        with jax.named_scope("layers"):
+            x, new_states = lax.scan(body, x, (gp, cache))
         return x, new_states
 
     if kind == "hybrid_super":
@@ -364,8 +374,10 @@ def run_group_decode(gp, x, cfg, kind, cache, *, pos, windowed=False,
                                          cache_pos=pos, positions=positions,
                                          ring=windowed)
             return y, (new_mstates, kv["k"], kv["v"])
-        x, (new_m, dk, dv) = lax.scan(body, x, (gp["mamba"], cache["mamba"],
-                                                cache["k"], cache["v"]))
+        with jax.named_scope("layers"):
+            x, (new_m, dk, dv) = lax.scan(body, x, (gp["mamba"],
+                                                    cache["mamba"],
+                                                    cache["k"], cache["v"]))
         return x, {"mamba": new_m, "k": put(cache["k"], dk, windowed),
                    "v": put(cache["v"], dv, windowed)}
 
@@ -375,6 +387,7 @@ def run_group_decode(gp, x, cfg, kind, cache, *, pos, windowed=False,
 # ---------------------------------------------------------------------------
 # Embedding / head / loss
 # ---------------------------------------------------------------------------
+@L.named_scope("embed")
 def embed_tokens(params, tokens, cfg, patch_embeds=None):
     x = params["embed"][tokens].astype(jnp.dtype(cfg.dtype))
     if cfg.scale_embeddings:
@@ -479,8 +492,10 @@ def prefill(params, batch, cfg, capacity: int, bspec=None, seq_axis=None):
         x, nc, _ = run_group_prefill(gp, x, cfg, kind, c, positions=positions,
                                      seq_axis=seq_axis)
         new_cache.append(nc)
-    x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    return logits_last(params, x[:, -1], cfg), new_cache
+    with jax.named_scope("logits"):
+        x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        logits = logits_last(params, x[:, -1], cfg)
+    return logits, new_cache
 
 
 def decode_step(params, cache, tokens, pos, cfg, bspec=None, windowed=False,
@@ -495,5 +510,7 @@ def decode_step(params, cache, tokens, pos, cfg, bspec=None, windowed=False,
         x, nc = run_group_decode(gp, x, cfg, kind, c, pos=pos, windowed=windowed,
                                  return_deltas=return_deltas)
         new_cache.append(nc)
-    x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    return logits_last(params, x[:, 0], cfg), new_cache
+    with jax.named_scope("logits"):
+        x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        logits = logits_last(params, x[:, 0], cfg)
+    return logits, new_cache
