@@ -28,9 +28,10 @@ def _maxerr(a, b) -> float:
 
 
 def bench_kernels():
-    # baseline rows pin the HARD-CODED tile defaults explicitly, so their
-    # numbers stay comparable across runs whether or not the autotune cache
-    # (which this suite fills below) is already warm
+    # baseline rows pin the default tiles explicitly (the decode kernel's
+    # derived from its shapes), so their numbers stay comparable across runs
+    # whether or not the autotune cache (which this suite fills below) is
+    # already warm
     from repro.perf import autotune
     rows = []
     from repro.kernels.flash_attention.ops import flash_attention
@@ -51,19 +52,21 @@ def bench_kernels():
                  f"interpret_vs_ref=x{t_pl / t_ref:.2f}(CPU-interpret),"
                  f"maxerr={err:.3e}"))
 
-    from repro.kernels.decode_attention.ops import decode_attention
+    from repro.kernels.decode_attention.ops import (decode_attention,
+                                                    decode_tiling)
     from repro.kernels.decode_attention.ref import decode_attention_ref
     S = 4096
     q1 = jax.random.normal(ks[0], (4, H, hd), jnp.float32)
     kc = jax.random.normal(ks[1], (4, S, KV, hd), jnp.float32)
     vc = jax.random.normal(ks[2], (4, S, KV, hd), jnp.float32)
     pos = jnp.asarray(S - 1, jnp.int32)
-    t_pl = _time(lambda a, b, c: decode_attention(
-        a, b, c, pos, **autotune.DEFAULTS["decode_attention"]), q1, kc, vc)
+    tiling = decode_tiling(4 * KV, S, H // KV, hd, jnp.float32)
+    derived = {"rows": tiling.rows, "block_k": tiling.block_k}
+    t_pl = _time(lambda a, b, c: decode_attention(a, b, c, pos, **derived),
+                 q1, kc, vc)
     t_ref = _time(jax.jit(lambda a, b, c: decode_attention_ref(a, b, c, pos)),
                   q1, kc, vc)
-    err = _maxerr(decode_attention(q1, kc, vc, pos,
-                                   **autotune.DEFAULTS["decode_attention"]),
+    err = _maxerr(decode_attention(q1, kc, vc, pos, **derived),
                   decode_attention_ref(q1, kc, vc, pos))
     rows.append(("kernel/decode_attention/4k", t_pl * 1e6,
                  f"interpret_vs_ref=x{t_pl / t_ref:.2f}(CPU-interpret),"
@@ -103,8 +106,10 @@ def bench_kernels():
 
     tuned = autotune.tune("decode_attention", "float32", BKV=4 * KV,
                           G=H // KV, hd=hd, S=S)["config"]
-    t_def = _time(lambda a, b, c: decode_attention(
-        a, b, c, pos, **autotune.DEFAULTS["decode_attention"]), q1, kc, vc)
+    tuned = decode_tiling(4 * KV, S, H // KV, hd, jnp.float32, **tuned)
+    tuned = {"rows": tuned.rows, "block_k": tuned.block_k}
+    t_def = _time(lambda a, b, c: decode_attention(a, b, c, pos, **derived),
+                  q1, kc, vc)
     t_tun = _time(lambda a, b, c: decode_attention(a, b, c, pos, **tuned),
                   q1, kc, vc)
     rows.append(("kernel/decode_attention/4k/autotuned", t_tun * 1e6,

@@ -1,10 +1,15 @@
 """Pallas TPU decode-attention kernel: one new token vs a KV cache.
 
-Grid = (B*KV, ns); the key axis is blocked (block_k) and accumulated with an
-online softmax in VMEM scratch.  K tiles entirely beyond ``pos`` (or outside
-the sliding window) are skipped with ``pl.when`` on the *traced* position —
-on TPU this saves HBM reads of the dead cache region.  The GQA group axis
-forms the matmul rows.
+Grid = (BKV / rows, S / block_k).  Each step takes a block of ``rows``
+(batch x KV-head) rows against a ``block_k`` slice of their cache and folds
+it into an online softmax kept in VMEM scratch; the GQA group axis forms the
+matmul rows of each (batched) product.  ``ops.decode_tiling`` picks the
+block sizes from the operand shapes.
+
+The K/V index map is clamped to the live tiles with the scalar-prefetched
+``pos``: a step past ``pos`` (or before the sliding window) maps to the
+nearest live tile, which the pipeline already holds, so the dead cache
+region is never fetched from HBM; ``pl.when`` then skips its compute.
 """
 
 from __future__ import annotations
@@ -18,10 +23,15 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_COMPILER_PARAMS = pltpu.CompilerParams(
-    dimension_semantics=("parallel", "arbitrary"))
-
 NEG_INF = -2.0 ** 30
+
+
+def _live_tiles(pos, *, block_k: int, ns: int, window: Optional[int]):
+    """(first, last) K tile holding a position the token attends to."""
+    last = jnp.minimum(pos // block_k, ns - 1)
+    if window is None:
+        return 0, last
+    return jnp.maximum(pos - window + 1, 0) // block_k, last
 
 
 def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
@@ -43,36 +53,35 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0] * scale                                  # (G, hd)
-        k = k_ref[0]                                          # (bk, hd)
-        v = v_ref[0]
-        s = lax.dot_general(q.astype(jnp.float32), k.astype(jnp.float32),
-                            (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (G, bk)
-        if logit_cap is not None:
+        q = q_ref[...] * jnp.asarray(scale, q_ref.dtype)      # (rows, G, hd)
+        v = v_ref[...]                                        # (rows, bk, hd)
+        s = lax.dot_general(q, k_ref[...], (((2,), (2,)), ((0,), (0,))),
+                            preferred_element_type=jnp.float32)
+        if logit_cap is not None:                             # (rows, G, bk)
             s = logit_cap * jnp.tanh(s / logit_cap)
-        kpos = k0 + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        kpos = k0 + lax.broadcasted_iota(jnp.int32, s.shape, 2)
         mask = kpos <= pos
         if window is not None:
             mask = mask & (kpos > pos - window)
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        m_prev = m_ref[:, :, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
         l_ref[...] = jnp.broadcast_to(
-            l_ref[:, :1] * corr + p.sum(axis=1, keepdims=True), l_ref.shape)
+            l_ref[:, :, :1] * corr + p.sum(axis=2, keepdims=True),
+            l_ref.shape)
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        pv = lax.dot_general(p, v.astype(jnp.float32),
-                             (((1,), (0,)), ((), ())),
+        pv = lax.dot_general(p.astype(v.dtype), v,
+                             (((2,), (1,)), ((0,), (0,))),
                              preferred_element_type=jnp.float32)
         acc_ref[...] = acc_ref[...] * corr + pv
 
     @pl.when(ki == ns - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[:, :, :1], 1e-30)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def decode_attention_fwd(
@@ -83,30 +92,37 @@ def decode_attention_fwd(
     *,
     window: Optional[int],
     logit_cap: Optional[float],
+    rows: int,
     block_k: int,
+    vmem_limit_bytes: int,
     interpret: bool,
 ) -> jax.Array:
     BKV, G, hd = q.shape
     S = k.shape[1]
-    assert S % block_k == 0, (S, block_k)
+    assert BKV % rows == 0 and S % block_k == 0, (BKV, rows, S, block_k)
     ns = S // block_k
     scale = hd ** -0.5
+
+    def kv_map(b, j, pos_ref):
+        first, last = _live_tiles(pos_ref[0], block_k=block_k, ns=ns,
+                                  window=window)
+        return b, jnp.minimum(jnp.maximum(j, first), last), 0
 
     kernel = functools.partial(_kernel, block_k=block_k, ns=ns, window=window,
                                logit_cap=logit_cap, scale=scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(BKV, ns),
+        grid=(BKV // rows, ns),
         in_specs=[
-            pl.BlockSpec((1, G, hd), lambda b, j, pos_ref: (b, 0, 0)),
-            pl.BlockSpec((1, block_k, hd), lambda b, j, pos_ref: (b, j, 0)),
-            pl.BlockSpec((1, block_k, hd), lambda b, j, pos_ref: (b, j, 0)),
+            pl.BlockSpec((rows, G, hd), lambda b, j, pos_ref: (b, 0, 0)),
+            pl.BlockSpec((rows, block_k, hd), kv_map),
+            pl.BlockSpec((rows, block_k, hd), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, G, hd), lambda b, j, pos_ref: (b, 0, 0)),
+        out_specs=pl.BlockSpec((rows, G, hd), lambda b, j, pos_ref: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((G, 128), jnp.float32),
-            pltpu.VMEM((G, 128), jnp.float32),
-            pltpu.VMEM((G, hd), jnp.float32),
+            pltpu.VMEM((rows, G, 128), jnp.float32),
+            pltpu.VMEM((rows, G, 128), jnp.float32),
+            pltpu.VMEM((rows, G, hd), jnp.float32),
         ],
     )
     return pl.pallas_call(
@@ -114,5 +130,7 @@ def decode_attention_fwd(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((BKV, G, hd), q.dtype),
         interpret=interpret,
-        compiler_params=_COMPILER_PARAMS,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes),
     )(pos, q, k, v)

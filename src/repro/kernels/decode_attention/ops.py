@@ -1,14 +1,16 @@
-"""Jit'd public wrapper for the decode-attention Pallas kernel.
+"""Jit'd public wrappers for the decode-attention Pallas kernels.
 
-``block_k=None`` consults the autotune cache (``repro.perf.autotune``)
-for the best-known tiling of this (shape-class, dtype, backend); an empty
-cache falls back to the historical 256 default.  Explicit kwargs win.
+The dense kernel's tiling comes from the operand shapes
+(``decode_tiling``).  ``rows=None, block_k=None`` first consults the
+autotune cache (``repro.perf.autotune``) for a tiling of this (shape-class,
+dtype, backend); an explicit value wins and the other is derived around it.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import math
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -23,16 +25,91 @@ def _on_cpu() -> bool:
     return jax.default_backend() == "cpu"
 
 
-DEFAULT_BLOCK_K = autotune.DEFAULTS["decode_attention"]["block_k"]
 DEFAULT_PAGE_SIZE = autotune.DEFAULTS["paged_decode_attention"]["page_size"]
 
+# A grid step has a fixed cost in the Pallas pipeline (about 0.4 us on a
+# TPU v5e), so each step should move enough K+V to hide it behind the HBM
+# transfer.  Rows come first: a larger block_k reads more dead positions
+# past ``pos``, a larger row block reads none.
+STEP_BYTES = 2 << 20        # K+V bytes a step aims to move
+MAX_BLOCK_K = 512           # block_k grows past this only when rows cannot
 
-def _resolve_block_k(block_k: Optional[int], dtype, BKV: int, G: int,
-                     hd: int, S: int) -> int:
+
+class DecodeTiling(NamedTuple):
+    rows: int          # (batch x KV-head) rows per grid step; divides BKV
+    block_k: int       # cache positions per grid step; divides S
+    steps: int         # grid steps per call
+    step_bytes: int    # K+V bytes one step moves
+    vmem_bytes: int    # VMEM of one step: double-buffered blocks, scratch
+                       # and the f32 scores, padded to (sublane, 128) tiles
+
+
+def _divisors(n: int) -> list:
+    small = [d for d in range(1, math.isqrt(n) + 1) if n % d == 0]
+    return sorted({*small, *(n // d for d in small)})
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def decode_tiling(BKV: int, S: int, G: int, hd: int, dtype, *,
+                  rows: Optional[int] = None,
+                  block_k: Optional[int] = None) -> DecodeTiling:
+    """Block sizes of the decode kernel for (BKV, S, hd) caches and G query
+    heads per KV head.  ``rows`` divides BKV and ``block_k`` divides S, so
+    the cache is never padded; a given value is lowered to the nearest
+    that does.  Otherwise block_k is the largest legal tile up to
+    MAX_BLOCK_K, then rows the fewest that move STEP_BYTES within the
+    autotuner's VMEM budget; when all BKV rows move less, block_k grows
+    towards S."""
+    sz = jnp.dtype(dtype).itemsize
+    budget = autotune.VMEM_BYTES
+    sub = 8 * max(1, 4 // sz)                 # sublanes of one VMEM tile
+    lanes = _round_up(hd, 128)                # hd 64 fills half the lanes
+
+    def step_bytes(r, bk):
+        return 2 * r * bk * hd * sz
+
+    def vmem(r, bk):
+        blocks = 2 * 2 * r * (_round_up(bk, sub) + _round_up(G, sub)) \
+            * lanes * sz                      # K, V, q, o; two buffers each
+        f32 = 4 * r * _round_up(G, 8) * (2 * 128 + lanes
+                                         + 3 * _round_up(bk, 128))
+        return blocks + f32
+
+    tiles = [d for d in _divisors(S) if d % sub == 0] or [S]
     if block_k is not None:
-        return block_k
-    cfg = autotune.lookup("decode_attention", dtype, BKV=BKV, G=G, hd=hd, S=S)
-    return cfg["block_k"] if cfg else DEFAULT_BLOCK_K
+        bk = max([d for d in tiles if d <= block_k] or [min(tiles)])
+    else:
+        bk = max([d for d in tiles if d <= MAX_BLOCK_K] or [min(tiles)])
+    if rows is not None:
+        r = max(d for d in _divisors(BKV) if d <= max(rows, 1))
+    else:
+        fits = [d for d in _divisors(BKV) if vmem(d, bk) <= budget] or [1]
+        r = next((d for d in fits if step_bytes(d, bk) >= STEP_BYTES),
+                 fits[-1])
+        if block_k is None and r == BKV and step_bytes(r, bk) < STEP_BYTES:
+            for d in tiles:
+                if d > bk and vmem(r, d) <= budget:
+                    bk = d
+                    if step_bytes(r, d) >= STEP_BYTES:
+                        break
+    return DecodeTiling(r, bk, (BKV // r) * (S // bk), step_bytes(r, bk),
+                        vmem(r, bk))
+
+
+def _resolve_tiling(rows, block_k, dtype, BKV, G, hd, S) -> DecodeTiling:
+    if rows is None and block_k is None:
+        cfg = autotune.lookup("decode_attention", dtype, BKV=BKV, G=G, hd=hd,
+                              S=S) or {}
+        rows, block_k = cfg.get("rows"), cfg.get("block_k")
+    return decode_tiling(BKV, S, G, hd, dtype, rows=rows, block_k=block_k)
+
+
+def _vmem_limit(BKV, S, G, hd, dtype, rows, block_k) -> int:
+    t = decode_tiling(BKV, S, G, hd, dtype, rows=rows, block_k=block_k)
+    return max(autotune.VMEM_BYTES, 2 * t.vmem_bytes)
 
 
 def decode_attention(
@@ -43,23 +120,23 @@ def decode_attention(
     *,
     window: Optional[int] = None,
     logit_cap: Optional[float] = None,
+    rows: Optional[int] = None,
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
-    block_k = _resolve_block_k(block_k, q.dtype,
-                               q.shape[0] * k_cache.shape[2],
-                               q.shape[1] // k_cache.shape[2], q.shape[2],
-                               k_cache.shape[1])
+    t = _resolve_tiling(rows, block_k, q.dtype, q.shape[0] * k_cache.shape[2],
+                        q.shape[1] // k_cache.shape[2], q.shape[2],
+                        k_cache.shape[1])
     if interpret is None:
         interpret = _on_cpu()
     return _decode_attention(q, k_cache, v_cache, pos, window=window,
-                             logit_cap=logit_cap, block_k=block_k,
-                             interpret=interpret)
+                             logit_cap=logit_cap, rows=t.rows,
+                             block_k=t.block_k, interpret=interpret)
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("window", "logit_cap", "block_k", "interpret"))
+    jax.jit, static_argnames=("window", "logit_cap", "rows", "block_k",
+                              "interpret"))
 def _decode_attention(
     q: jax.Array,
     k_cache: jax.Array,
@@ -68,27 +145,23 @@ def _decode_attention(
     *,
     window: Optional[int],
     logit_cap: Optional[float],
+    rows: int,
     block_k: int,
     interpret: bool,
 ) -> jax.Array:
     B, H, hd = q.shape
     _, S, KV, _ = k_cache.shape
     G = H // KV
-
-    block_k = min(block_k, S)
-    pad = (-S) % block_k
-    if pad:
-        k_cache = jnp.pad(k_cache, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v_cache = jnp.pad(v_cache, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    Sp = k_cache.shape[1]
-
     q3 = q.reshape(B, KV, G, hd).reshape(B * KV, G, hd)
-    k3 = k_cache.transpose(0, 2, 1, 3).reshape(B * KV, Sp, hd)
-    v3 = v_cache.transpose(0, 2, 1, 3).reshape(B * KV, Sp, hd)
+    k3 = k_cache.transpose(0, 2, 1, 3).reshape(B * KV, S, hd)
+    v3 = v_cache.transpose(0, 2, 1, 3).reshape(B * KV, S, hd)
     pos_arr = jnp.asarray(pos, jnp.int32).reshape(1)
 
     out = decode_attention_fwd(q3, k3, v3, pos_arr, window=window,
-                               logit_cap=logit_cap, block_k=block_k,
+                               logit_cap=logit_cap, rows=rows,
+                               block_k=block_k,
+                               vmem_limit_bytes=_vmem_limit(
+                                   B * KV, S, G, hd, q.dtype, rows, block_k),
                                interpret=interpret)
     return out.reshape(B, KV, G, hd).reshape(B, H, hd)
 
@@ -101,24 +174,25 @@ def decode_attention_kvmajor(
     *,
     window=None,
     logit_cap=None,
+    rows: Optional[int] = None,
     block_k: Optional[int] = None,
     interpret=None,
 ):
     """Like decode_attention but takes the (B, KV, S, hd) cache layout the
     model uses — a pure reshape, no transpose."""
-    block_k = _resolve_block_k(block_k, q.dtype,
-                               q.shape[0] * k_cache.shape[1],
-                               q.shape[1] // k_cache.shape[1], q.shape[2],
-                               k_cache.shape[2])
+    t = _resolve_tiling(rows, block_k, q.dtype, q.shape[0] * k_cache.shape[1],
+                        q.shape[1] // k_cache.shape[1], q.shape[2],
+                        k_cache.shape[2])
     if interpret is None:
         interpret = _on_cpu()
     return _decode_attention_kvmajor(q, k_cache, v_cache, pos, window=window,
-                                     logit_cap=logit_cap, block_k=block_k,
-                                     interpret=interpret)
+                                     logit_cap=logit_cap, rows=t.rows,
+                                     block_k=t.block_k, interpret=interpret)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("window", "logit_cap", "block_k", "interpret"))
+    jax.jit, static_argnames=("window", "logit_cap", "rows", "block_k",
+                              "interpret"))
 def _decode_attention_kvmajor(
     q: jax.Array,
     k_cache: jax.Array,
@@ -127,24 +201,22 @@ def _decode_attention_kvmajor(
     *,
     window,
     logit_cap,
+    rows: int,
     block_k: int,
     interpret: bool,
 ):
     B, H, hd = q.shape
     _, KV, S, _ = k_cache.shape
     G = H // KV
-    block_k = min(block_k, S)
-    pad = (-S) % block_k
-    if pad:
-        k_cache = jnp.pad(k_cache, ((0, 0), (0, 0), (0, pad), (0, 0)))
-        v_cache = jnp.pad(v_cache, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    Sp = k_cache.shape[2]
     q3 = q.reshape(B * KV, G, hd)
-    k3 = k_cache.reshape(B * KV, Sp, hd)
-    v3 = v_cache.reshape(B * KV, Sp, hd)
+    k3 = k_cache.reshape(B * KV, S, hd)
+    v3 = v_cache.reshape(B * KV, S, hd)
     pos_arr = jnp.asarray(pos, jnp.int32).reshape(1)
     out = decode_attention_fwd(q3, k3, v3, pos_arr, window=window,
-                               logit_cap=logit_cap, block_k=block_k,
+                               logit_cap=logit_cap, rows=rows,
+                               block_k=block_k,
+                               vmem_limit_bytes=_vmem_limit(
+                                   B * KV, S, G, hd, q.dtype, rows, block_k),
                                interpret=interpret)
     return out.reshape(B, H, hd)
 

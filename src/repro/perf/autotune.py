@@ -42,16 +42,19 @@ from repro.perf import profile_store
 from repro.perf.roofline import HBM_BW, PEAK_FLOPS
 
 VMEM_BYTES = 16 * 2 ** 20       # per-core VMEM budget (TPU v5e)
+STEP_S = 0.4e-6                 # fixed cost of one Pallas grid step (v5e)
 PRUNE_RATIO = 3.0               # keep candidates within this factor of the
                                 # best modeled bound time
 DEFAULT_CACHE_DIR = profile_store.DEFAULT_STORE_DIR
 _LEGACY_CACHE_FILE = "autotune_cache.json"
 
-# Historical hard-coded defaults — the fallback when the cache is empty,
-# and always kept in the candidate set so tuning can only improve on them.
+# Hard-coded defaults — the fallback when the cache is empty, and always
+# kept in the candidate set so tuning can only improve on them.  The decode
+# kernel's None values are derived from the operand shapes
+# (``kernels/decode_attention/ops.py``, ``decode_tiling``).
 DEFAULTS = {
     "flash_attention": {"block_q": 128, "block_k": 128},
-    "decode_attention": {"block_k": 256},
+    "decode_attention": {"rows": None, "block_k": None},
     "paged_decode_attention": {"page_size": 64},
     "ssd_scan": {"chunk": 128},
 }
@@ -199,7 +202,8 @@ def _flash_candidates(cls: dict) -> list:
     return out or [dict(DEFAULTS["flash_attention"])]
 
 
-def _flash_model(cls: dict, cand: dict, sz: int) -> tuple:
+def _flash_model(cls: dict, cand: dict, dtype: str) -> tuple:
+    sz = np.dtype(dtype).itemsize
     G, hd, Tq, Tk = cls["G"], cls["hd"], cls["Tq"], cls["Tk"]
     bq, bk = cand["block_q"], cand["block_k"]
     nq, nk = Tq // bq, Tk // bk
@@ -214,21 +218,25 @@ def _flash_model(cls: dict, cand: dict, sz: int) -> tuple:
 
 
 def _decode_candidates(cls: dict) -> list:
-    out = [{"block_k": bk} for bk in (64, 128, 256, 512, 1024)
-           if bk <= cls["S"]]
-    return out or [dict(DEFAULTS["decode_attention"])]
+    return [dict(DEFAULTS["decode_attention"])] + [
+        {"rows": r, "block_k": bk} for r in (1, 8, 32)
+        for bk in (128, 256, 512, 1024, 2048)
+        if r <= cls["BKV"] and bk <= cls["S"]]
 
 
-def _decode_model(cls: dict, cand: dict, sz: int) -> tuple:
-    G, hd, S = cls["G"], cls["hd"], cls["S"]
-    bk = cand["block_k"]
-    ns = S // bk
-    bytes_ = sz * (2 * S * hd + G * hd * ns + G * hd)
-    flops = 4.0 * G * S * hd
-    eff = (min(G, 128) / 128.0) * (min(bk, 128) / 128.0)
-    bound = max(flops / (PEAK_FLOPS * eff), bytes_ / HBM_BW)
-    vmem = sz * (G * hd + 2 * bk * hd) + 4 * (2 * G * 128 + G * hd + G * bk)
-    return bound, vmem
+def _decode_model(cls: dict, cand: dict, dtype: str) -> tuple:
+    from repro.kernels.decode_attention.ops import decode_tiling
+    BKV, G, hd, S = cls["BKV"], cls["G"], cls["hd"], cls["S"]
+    t = decode_tiling(BKV, S, G, hd, dtype, rows=cand["rows"],
+                      block_k=cand["block_k"])
+    # every K/V tile once, q re-read per k step, out written once
+    bytes_ = (t.steps * t.step_bytes
+              + np.dtype(dtype).itemsize * BKV * G * hd * (S // t.block_k + 1))
+    flops = 4.0 * BKV * G * S * hd
+    eff = (min(G, 128) / 128.0) * (min(t.block_k, 128) / 128.0)
+    bound = (max(flops / (PEAK_FLOPS * eff), bytes_ / HBM_BW)
+             + t.steps * STEP_S)
+    return bound, t.vmem_bytes
 
 
 def _paged_candidates(cls: dict) -> list:
@@ -236,11 +244,12 @@ def _paged_candidates(cls: dict) -> list:
     return out or [dict(DEFAULTS["paged_decode_attention"])]
 
 
-def _paged_model(cls: dict, cand: dict, sz: int) -> tuple:
-    # a page is the paged kernel's k-block: same arithmetic-intensity terms
-    # as the dense decode kernel at block_k = page_size (the block table
-    # adds only a few scalar-prefetch bytes per grid step)
-    return _decode_model(cls, {"block_k": cand["page_size"]}, sz)
+def _paged_model(cls: dict, cand: dict, dtype: str) -> tuple:
+    # a page is the paged kernel's k-block, one (batch x KV-head) row per
+    # grid step: the dense decode kernel's terms at rows = 1, block_k =
+    # page_size (the block table adds a few scalar-prefetch bytes per step)
+    return _decode_model(cls, {"rows": 1, "block_k": cand["page_size"]},
+                         dtype)
 
 
 def _ssd_candidates(cls: dict) -> list:
@@ -249,7 +258,8 @@ def _ssd_candidates(cls: dict) -> list:
     return out or [dict(DEFAULTS["ssd_scan"])]
 
 
-def _ssd_model(cls: dict, cand: dict, sz: int) -> tuple:
+def _ssd_model(cls: dict, cand: dict, dtype: str) -> tuple:
+    sz = np.dtype(dtype).itemsize
     P, N, T = cls["P"], cls["N"], cls["T"]
     c = cand["chunk"]
     # intra-chunk terms are quadratic in the chunk: smaller chunks do fewer
@@ -278,10 +288,9 @@ def prune_candidates(kernel: str, cls: dict, dtype: str,
     only ever remove challengers, never the fallback."""
     cands_fn, model_fn = _KERNELS[kernel]
     cands = cands_fn(cls)
-    sz = np.dtype(dtype).itemsize
     scored = []
     for cand in cands:
-        bound, vmem = model_fn(cls, cand, sz)
+        bound, vmem = model_fn(cls, cand, dtype)
         scored.append((cand, bound, vmem))
     feasible = [s for s in scored if s[2] <= VMEM_BYTES]
     if not feasible:
@@ -330,16 +339,21 @@ def _flash_bench(cls: dict, dtype: str, cand: dict) -> Callable:
 def _decode_bench(cls: dict, dtype: str, cand: dict) -> Callable:
     import jax
     import jax.numpy as jnp
-    from repro.kernels.decode_attention.ops import decode_attention
+    from repro.kernels.decode_attention.ops import (decode_attention,
+                                                    decode_tiling)
     B = cls["BKV"]
     G, hd, S = cls["G"], cls["hd"], cls["S"]
+    # the tiling the kernel runs, resolved here: passing None would send
+    # the wrapper back to the cache this search is filling
+    t = decode_tiling(B, S, G, hd, dtype, rows=cand["rows"],
+                      block_k=cand["block_k"])
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
     q = jax.random.normal(ks[0], (B, G, hd), jnp.float32).astype(dtype)
     kc = jax.random.normal(ks[1], (B, S, 1, hd), jnp.float32).astype(dtype)
     vc = jax.random.normal(ks[2], (B, S, 1, hd), jnp.float32).astype(dtype)
     pos = jnp.asarray(S - 1, jnp.int32)
-    return lambda: decode_attention(q, kc, vc, pos,
-                                    block_k=cand["block_k"])
+    return lambda: decode_attention(q, kc, vc, pos, rows=t.rows,
+                                    block_k=t.block_k)
 
 
 def _paged_bench(cls: dict, dtype: str, cand: dict) -> Callable:

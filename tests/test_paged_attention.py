@@ -6,9 +6,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels.decode_attention.decode_attention import _live_tiles
 from repro.kernels.decode_attention.ops import (decode_attention_kvmajor,
+                                                decode_tiling,
                                                 paged_decode_attention,
                                                 resolve_page_size)
+from repro.perf import autotune
 from repro.kernels.decode_attention.ref import (decode_attention_ref,
                                                 decode_attention_ref_ragged)
 
@@ -118,21 +121,36 @@ def test_resolve_page_size_prefers_explicit_then_default():
 # --- satellite: kv-major wrapper on the shapes the paged variant stresses ---
 
 KVMAJOR_CASES = [
-    # (B, S, H, KV, hd, pos, window, cap) — ragged/odd kv_len, non-pow2
-    # heads, single-slot batches
-    (2, 300, 8, 2, 64, 299, None, None),      # odd S: padding path
+    # (B, S, H, KV, hd, pos, window, cap[, tiling]) — ragged/odd kv_len,
+    # non-pow2 heads, single-slot batches; an explicit tiling splits a small
+    # cache into several row blocks and K tiles
+    (2, 300, 8, 2, 64, 299, None, None),      # odd S: one whole-cache tile
     (3, 300, 6, 3, 64, 150, None, None),      # non-pow2 heads
     (1, 512, 4, 1, 128, 37, None, None),      # single slot, short kv_len
     (1, 640, 12, 3, 64, 633, 128, None),      # single slot + window
     (2, 384, 10, 5, 32, 65, None, 40.0),      # non-pow2 heads + cap
     (1, 256, 8, 2, 64, 0, None, None),        # single slot, first token
+    (3, 1024, 9, 3, 64, 700, None, None),     # BKV 9: odd row count
+    (2, 512, 6, 3, 64, 300, None, None,       # rows 4 does not divide
+     {"rows": 4, "block_k": 128}),            # BKV 6: lowered to 3
+    (2, 512, 8, 2, 64, 256, None, None,       # pos on a tile boundary
+     {"rows": 2, "block_k": 128}),
+    (2, 512, 8, 2, 64, 0, None, None,         # pos 0, three dead tiles
+     {"rows": 4, "block_k": 128}),
+    (2, 512, 8, 2, 64, 450, None, None,       # pos in the last tile
+     {"rows": 2, "block_k": 128}),
+    (2, 512, 8, 2, 64, 400, 200, None,        # window opens mid-tile
+     {"rows": 4, "block_k": 128}),
+    (2, 512, 15, 5, 64, 383, None, None),     # the benchmark's 15/5 x 64
+    (2, 512, 15, 5, 64, 383, None, None,      # ...over several row blocks
+     {"rows": 2, "block_k": 128}),
 ]
 
 
 @pytest.mark.parametrize("case", KVMAJOR_CASES, ids=str)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_decode_attention_kvmajor_matches_ref(case, dtype):
-    B, S, H, KV, hd, pos, window, cap = case
+    B, S, H, KV, hd, pos, window, cap, *tiling = case
     ks = jax.random.split(jax.random.PRNGKey(14), 3)
     q = _rand(ks[0], (B, H, hd), dtype)
     k = _rand(ks[1], (B, S, KV, hd), dtype)
@@ -141,8 +159,46 @@ def test_decode_attention_kvmajor_matches_ref(case, dtype):
     # the kv-major entry point takes the model's (B, KV, S, hd) layout
     out = decode_attention_kvmajor(q, k.transpose(0, 2, 1, 3),
                                    v.transpose(0, 2, 1, 3), p,
-                                   window=window, logit_cap=cap)
+                                   window=window, logit_cap=cap,
+                                   **(tiling[0] if tiling else {}))
     ref = decode_attention_ref(q, k, v, p, window=window, logit_cap=cap)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("BKV", [320, 160])
+def test_decode_tiling_streams_large_tiles_without_padding(BKV):
+    """Both cells' decode shapes (B 64 and 32 x 5 KV heads, a 2048-position
+    bf16 cache of 64-wide heads): whole tiles, at least 1 MB of K+V per
+    grid step, within the VMEM budget."""
+    t = decode_tiling(BKV, 2048, 3, 64, jnp.bfloat16)
+    assert BKV % t.rows == 0 and 2048 % t.block_k == 0
+    assert t.steps == (BKV // t.rows) * (2048 // t.block_k)
+    assert t.step_bytes == 2 * t.rows * t.block_k * 64 * 2
+    assert t.step_bytes >= 1 << 20
+    assert t.vmem_bytes <= autotune.VMEM_BYTES
+
+
+def test_decode_tiling_small_shapes_and_given_values():
+    one = decode_tiling(1, 2048, 3, 64, jnp.bfloat16)
+    assert one.rows == 1 and one.block_k == 2048     # the whole row
+    assert decode_tiling(320, 2048, 3, 64, jnp.bfloat16,
+                         block_k=256).block_k == 256  # a given value wins
+    lowered = decode_tiling(6, 512, 2, 64, jnp.float32, rows=4, block_k=200)
+    assert (lowered.rows, lowered.block_k) == (3, 128)
+    odd = decode_tiling(4, 300, 2, 64, jnp.float32)   # no 8-aligned divisor
+    assert odd.block_k == 300
+
+
+@pytest.mark.parametrize("pos,window,tiles", [
+    (0, None, (0, 0)), (127, None, (0, 0)), (128, None, (0, 1)),
+    (511, None, (0, 3)), (400, 200, (1, 3)), (400, 144, (2, 3)),
+    (100, 300, (0, 0)),
+])
+def test_decode_kv_index_map_stops_at_live_tiles(pos, window, tiles):
+    """K/V tiles outside [first, last] are never fetched: the index map
+    clamps the grid's K axis to them (block_k 128, four tiles)."""
+    first, last = _live_tiles(jnp.asarray(pos), block_k=128, ns=4,
+                              window=window)
+    assert (int(first), int(last)) == tiles

@@ -79,16 +79,20 @@ def test_flash_attention_compiles(one_chip):
 
 
 def test_decode_attention_kvmajor_compiles(one_chip):
+    """A small cache, and decode-long's (B 64, S 2048): the kernel takes
+    the cache as it lies, with no pad around it."""
     from repro.kernels.decode_attention.ops import decode_attention_kvmajor
     cfg = SMOLLM
-    B, S = 8, 1024
-    q = _spec(one_chip, (B, cfg.num_heads, cfg.head_dim))
-    kv = _spec(one_chip, (B, cfg.num_kv_heads, S, cfg.head_dim))
-    pos = _spec(one_chip, (), jnp.int32)
-    _assert_kernel(_compile(
-        lambda q, k, v, pos: decode_attention_kvmajor(q, k, v, pos,
-                                                      interpret=False),
-        q, kv, kv, pos))
+    for B, S in ((8, 1024), (64, 2048)):
+        q = _spec(one_chip, (B, cfg.num_heads, cfg.head_dim))
+        kv = _spec(one_chip, (B, cfg.num_kv_heads, S, cfg.head_dim))
+        pos = _spec(one_chip, (), jnp.int32)
+        compiled = _compile(
+            lambda q, k, v, pos: decode_attention_kvmajor(q, k, v, pos,
+                                                          interpret=False),
+            q, kv, kv, pos)
+        _assert_kernel(compiled)
+        assert " pad(" not in compiled.as_text(), (B, S)
 
 
 @pytest.mark.parametrize("page_size", [16, 64, 128])
