@@ -52,6 +52,16 @@ class ModelConfig:
     scale_embeddings: bool = False      # gemma2 multiplies embeds by sqrt(d)
     tie_embeddings: bool = True
 
+    # --- GPT-BigCode block (granite-20b) -------------------------------------
+    norm_type: str = "rmsnorm"          # 'rmsnorm' | 'layernorm' (with bias)
+    mlp_type: str = "swiglu"            # 'swiglu': silu(h Wg) * (h Wi) Wo, no
+                                        # biases; 'gelu': gelu(h Wi + bi) Wo + bo,
+                                        # GELU in its tanh form
+    attention_out_bias: bool = False    # bias on the attention output projection
+    learned_positions: int = 0          # rows of a learned absolute position
+                                        # table added to the embeddings (no
+                                        # RoPE); 0: rotary embeddings
+
     # --- MoE ----------------------------------------------------------------
     num_experts: int = 0
     num_experts_per_tok: int = 0
@@ -139,11 +149,16 @@ class ModelConfig:
         attn = d * h * hd + 2 * d * kv * hd + h * hd * d
         if self.attention_bias:
             attn += (h + 2 * kv) * hd
+        if self.attention_out_bias:
+            attn += d
         mlp = 3 * d * ff  # gate/up/down
+        if self.mlp_type == "gelu":
+            mlp = 2 * d * ff + ff + d  # up/down and their biases
         if self.num_experts:
             eff = self.moe_d_ff or ff
             mlp = self.num_experts * 3 * d * eff + d * self.num_experts  # + router
-        norm = 2 * d * (2 if self.post_block_norm else 1)
+        norm_size = 2 * d if self.norm_type == "layernorm" else d
+        norm = 2 * norm_size * (2 if self.post_block_norm else 1)
 
         def mamba_block_params() -> int:
             d_in = self.ssm_expand * d
@@ -155,7 +170,7 @@ class ModelConfig:
             out = d_in * d
             return zxbcdt + conv + extra + out + d
 
-        total = v * d  # embed
+        total = v * d + self.learned_positions * d  # embeddings
         if not self.tie_embeddings:
             total += v * d
         for kind, count in self.layer_groups:
@@ -170,7 +185,7 @@ class ModelConfig:
                 total += attn + mlp + norm  # shared (tied) attention block, counted once
             elif kind == "local_global":
                 total += count * 2 * (attn + mlp + norm)
-        total += d  # final norm
+        total += norm_size  # final norm
         return total
 
     def active_param_count(self) -> int:

@@ -23,14 +23,20 @@ def _axes_tuple(ax):
     return (ax,) if isinstance(ax, str) else tuple(ax)
 
 
-def _append_local(c_loc, d_loc, pos, *, seq_axes, mesh_axis_sizes, axis=3):
-    """Per-device body: write d (…,1,…) into c at global slot pos (mod cap)."""
-    s_loc = c_loc.shape[axis]
+def _shard(seq_axes, mesh_axis_sizes):
+    """(index of this device's part of the sequence, number of parts)."""
     shard_idx = jnp.zeros((), jnp.int32)
     total = 1
     for a in seq_axes:
         shard_idx = shard_idx * mesh_axis_sizes[a] + lax.axis_index(a)
         total *= mesh_axis_sizes[a]
+    return shard_idx, total
+
+
+def append_local(c_loc, d_loc, pos, *, seq_axes, mesh_axis_sizes, axis=3):
+    """Per-device body: write d (…,1,…) into c at global slot pos (mod cap)."""
+    s_loc = c_loc.shape[axis]
+    shard_idx, total = _shard(seq_axes, mesh_axis_sizes)
     cap = s_loc * total
     slot = pos % cap
     start = shard_idx * s_loc
@@ -52,13 +58,40 @@ def append_kv(cache_leaf, delta_leaf, pos, spec: P, minfo, axis: int = 3):
 
     delta_spec = list(spec)
     delta_spec[axis] = None
-    fn = functools.partial(_append_local, seq_axes=seq_axes,
+    fn = functools.partial(append_local, seq_axes=seq_axes,
                            mesh_axis_sizes=minfo.axis_sizes, axis=axis)
     return jax.shard_map(
         fn, mesh=minfo.mesh,
         in_specs=(spec, P(*delta_spec), P()),
         out_specs=spec,
     )(cache_leaf, delta_leaf, pos)
+
+
+def _write_local(c_loc, u, pos, *, seq_axes, mesh_axis_sizes, axis):
+    """Per-device body: write the block u (…,T,…), whole on every device,
+    at global positions [pos, pos + T) of the cache whose part c_loc holds."""
+    s_loc, T = c_loc.shape[axis], u.shape[axis]
+    shard_idx, _ = _shard(seq_axes, mesh_axis_sizes)
+    src = shard_idx * s_loc + jnp.arange(s_loc) - pos   # row of u, if any
+    piece = jnp.take(u, jnp.clip(src, 0, T - 1), axis=axis)
+    valid = ((src >= 0) & (src < T)).reshape(
+        [s_loc if i == axis else 1 for i in range(c_loc.ndim)])
+    return jnp.where(valid, piece.astype(c_loc.dtype), c_loc)
+
+
+def write_kv(cache_leaf, block, pos, spec: P, minfo, axis: int = 3):
+    """Write ``block`` (…,T,…) at positions [pos, pos + T) of the cache
+    whose sequence axis ``axis`` is split as ``spec`` says: each device
+    writes the rows that fall in its own part, from a block every device
+    holds whole (a prefill's K/V), so no device gathers the cache."""
+    seq_axes = _axes_tuple(spec[axis])
+    block_spec = list(spec)
+    block_spec[axis] = None
+    fn = functools.partial(_write_local, seq_axes=seq_axes,
+                           mesh_axis_sizes=minfo.axis_sizes, axis=axis)
+    return jax.shard_map(fn, mesh=minfo.mesh,
+                         in_specs=(spec, P(*block_spec), P()),
+                         out_specs=spec)(cache_leaf, block, pos)
 
 
 def apply_cache_deltas(cache, deltas, pos, cache_specs, minfo):

@@ -10,10 +10,12 @@ Params
         axis (whisper, zamba2) or KV==1 with Q-heads divisible (granite MQA).
         Otherwise attention weights are replicated over 'model' (the GQA
         reshape would not propagate under GSPMD) — a recorded baseline cost.
-      - MLP: d_ff axis (always divisible for the assigned archs).
+      - MLP: d_ff axis (always divisible for the assigned archs), with the
+        up-projection's bias; the output bias stays replicated.
       - MoE: expert axis when divisible (qwen3: 128/16), else per-expert d_ff
         (mixtral: 8 experts, 16384 d_ff).
-      - embeddings / lm_head: vocab axis when divisible, else d_model axis.
+      - embeddings / lm_head: vocab axis when divisible, else d_model axis;
+        a learned position table, norms and their biases are replicated.
       - Mamba blocks: replicated over 'model' (TP for SSD needs grouped B/C —
         beyond baseline), sharded over 'data' in train mode.
   * FSDP over 'data' (train mode, and inference when the TP-sharded params
@@ -152,7 +154,7 @@ def _leaf_spec(path_names: list, shape: tuple, cfg: ModelConfig,
             tp[last_dims(3)] = "model"
         elif _div(shape[last_dims(2)], model):
             tp[last_dims(2)] = "model"
-    elif name in ("wi", "wg") and in_mlp:
+    elif name in ("wi", "wg", "bi") and in_mlp:
         if _div(shape[-1], model):
             tp[last_dims(1)] = "model"          # dense MLP (…, d, f) -> f
     elif name == "wo" and in_mlp:

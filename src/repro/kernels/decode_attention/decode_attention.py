@@ -10,6 +10,12 @@ The K/V index map is clamped to the live tiles with the scalar-prefetched
 ``pos``: a step past ``pos`` (or before the sliding window) maps to the
 nearest live tile, which the pipeline already holds, so the dead cache
 region is never fetched from HBM; ``pl.when`` then skips its compute.
+
+``pos`` may lie outside the cache: past its end every position is live,
+below 0 none is (one shard of a cache split by positions), and the output
+is 0.  With ``stats`` the kernel also returns the softmax's running max
+and sum of exponentials per query head, so that outputs over disjoint
+parts of a cache can be combined.
 """
 
 from __future__ import annotations
@@ -28,15 +34,16 @@ NEG_INF = -2.0 ** 30
 
 def _live_tiles(pos, *, block_k: int, ns: int, window: Optional[int]):
     """(first, last) K tile holding a position the token attends to."""
-    last = jnp.minimum(pos // block_k, ns - 1)
+    last = jnp.clip(pos // block_k, 0, ns - 1)
     if window is None:
         return 0, last
     return jnp.maximum(pos - window + 1, 0) // block_k, last
 
 
-def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-            block_k: int, ns: int, window: Optional[int],
-            logit_cap: Optional[float], scale: float):
+def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, *refs, block_k: int, ns: int,
+            window: Optional[int], logit_cap: Optional[float], scale: float,
+            stats: bool):
+    s_ref, m_ref, l_ref, acc_ref = refs if stats else (None, *refs)
     ki = pl.program_id(1)
     k0 = ki * block_k
     pos = pos_ref[0]
@@ -82,6 +89,9 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     def _finalize():
         l = jnp.maximum(l_ref[:, :, :1], 1e-30)
         o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
+        if stats:                 # lane 0: running max, lane 1: sum of exp
+            lane = lax.broadcasted_iota(jnp.int32, s_ref.shape, 2)
+            s_ref[...] = jnp.where(lane == 0, m_ref[...], l_ref[...])
 
 
 def decode_attention_fwd(
@@ -96,7 +106,11 @@ def decode_attention_fwd(
     block_k: int,
     vmem_limit_bytes: int,
     interpret: bool,
-) -> jax.Array:
+    stats: bool = False,
+):
+    """Returns o (BKV, G, hd); with ``stats`` also (BKV, G, 128) float32
+    holding each row's running max in lane 0 and its sum of exp in lane 1
+    (NEG_INF and 0 where no position is live)."""
     BKV, G, hd = q.shape
     S = k.shape[1]
     assert BKV % rows == 0 and S % block_k == 0, (BKV, rows, S, block_k)
@@ -109,7 +123,14 @@ def decode_attention_fwd(
         return b, jnp.minimum(jnp.maximum(j, first), last), 0
 
     kernel = functools.partial(_kernel, block_k=block_k, ns=ns, window=window,
-                               logit_cap=logit_cap, scale=scale)
+                               logit_cap=logit_cap, scale=scale, stats=stats)
+    out_block = pl.BlockSpec((rows, G, hd), lambda b, j, pos_ref: (b, 0, 0))
+    out_shape = jax.ShapeDtypeStruct((BKV, G, hd), q.dtype)
+    if stats:
+        out_block = [out_block, pl.BlockSpec((rows, G, 128),
+                                             lambda b, j, pos_ref: (b, 0, 0))]
+        out_shape = [out_shape, jax.ShapeDtypeStruct((BKV, G, 128),
+                                                     jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(BKV // rows, ns),
@@ -118,7 +139,7 @@ def decode_attention_fwd(
             pl.BlockSpec((rows, block_k, hd), kv_map),
             pl.BlockSpec((rows, block_k, hd), kv_map),
         ],
-        out_specs=pl.BlockSpec((rows, G, hd), lambda b, j, pos_ref: (b, 0, 0)),
+        out_specs=out_block,
         scratch_shapes=[
             pltpu.VMEM((rows, G, 128), jnp.float32),
             pltpu.VMEM((rows, G, 128), jnp.float32),
@@ -128,7 +149,7 @@ def decode_attention_fwd(
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((BKV, G, hd), q.dtype),
+        out_shape=out_shape,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),

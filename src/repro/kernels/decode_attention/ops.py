@@ -177,9 +177,16 @@ def decode_attention_kvmajor(
     rows: Optional[int] = None,
     block_k: Optional[int] = None,
     interpret=None,
+    stats: bool = False,
 ):
     """Like decode_attention but takes the (B, KV, S, hd) cache layout the
-    model uses — a pure reshape, no transpose."""
+    model uses — a pure reshape, no transpose.
+
+    ``pos`` may lie outside [0, S): one shard of a cache split by
+    positions, with the token's own position counted from the shard's
+    first.  With ``stats`` returns (o, m, l): m, l (B, H) float32 are the
+    softmax's running max of the scaled scores and its sum of exp(score -
+    m), with m = -inf and l = 0 (and o = 0) where no position is live."""
     t = _resolve_tiling(rows, block_k, q.dtype, q.shape[0] * k_cache.shape[1],
                         q.shape[1] // k_cache.shape[1], q.shape[2],
                         k_cache.shape[2])
@@ -187,12 +194,13 @@ def decode_attention_kvmajor(
         interpret = _on_cpu()
     return _decode_attention_kvmajor(q, k_cache, v_cache, pos, window=window,
                                      logit_cap=logit_cap, rows=t.rows,
-                                     block_k=t.block_k, interpret=interpret)
+                                     block_k=t.block_k, interpret=interpret,
+                                     stats=stats)
 
 
 @functools.partial(
     jax.jit, static_argnames=("window", "logit_cap", "rows", "block_k",
-                              "interpret"))
+                              "interpret", "stats"))
 def _decode_attention_kvmajor(
     q: jax.Array,
     k_cache: jax.Array,
@@ -204,6 +212,7 @@ def _decode_attention_kvmajor(
     rows: int,
     block_k: int,
     interpret: bool,
+    stats: bool,
 ):
     B, H, hd = q.shape
     _, KV, S, _ = k_cache.shape
@@ -217,8 +226,12 @@ def _decode_attention_kvmajor(
                                block_k=block_k,
                                vmem_limit_bytes=_vmem_limit(
                                    B * KV, S, G, hd, q.dtype, rows, block_k),
-                               interpret=interpret)
-    return out.reshape(B, H, hd)
+                               interpret=interpret, stats=stats)
+    if not stats:
+        return out.reshape(B, H, hd)
+    out, st = out
+    m, l = st[..., 0].reshape(B, H), st[..., 1].reshape(B, H)
+    return out.reshape(B, H, hd), jnp.where(l > 0, m, -jnp.inf), l
 
 
 def resolve_page_size(dtype, *, B: int, H: int, KV: int, hd: int,

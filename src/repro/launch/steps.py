@@ -16,8 +16,9 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import InputShape, ModelConfig
-from repro.distributed import sharding as shd
+from repro.distributed import cache_update, sharding as shd
 from repro.models import api
+from repro.models.layers import TensorParallel
 from repro.training import adamw
 
 Array = jax.Array
@@ -26,6 +27,31 @@ Array = jax.Array
 def _named(minfo, spec_tree):
     return jax.tree.map(lambda s: NamedSharding(minfo.mesh, s), spec_tree,
                         is_leaf=lambda x: isinstance(x, P))
+
+
+def kernel_tp(cfg: ModelConfig, minfo: shd.MeshInfo, bspec,
+              cache_specs=None) -> Optional[TensorParallel]:
+    """The tensor-parallel layout under which the Pallas attention kernels
+    run on each shard (``layers.cp_decode_attention``,
+    ``layers.tp_flash_attention``), with the cache writes of that layout
+    (``distributed.cache_update``), or None where they run whole: one
+    shard, XLA attention, heads that do not split over the model axis, or
+    a K/V cache whose positions are not split over exactly that axis."""
+    axis = "model"
+    if (cfg.kernel_impl != "pallas" or minfo.model == 1
+            or not shd.attn_head_tp(cfg, minfo.model)):
+        return None
+    for s in jax.tree.leaves(cache_specs or [],
+                             is_leaf=lambda x: isinstance(x, P)):
+        if s[-2] not in (axis, (axis,)):
+            return None
+    append = functools.partial(cache_update.append_local, seq_axes=(axis,),
+                               mesh_axis_sizes=minfo.axis_sizes, axis=2)
+    # the prefill's stacked K/V: (count, B, KV, T, hd)
+    write = functools.partial(cache_update.write_kv,
+                              spec=P(None, bspec, None, axis, None),
+                              minfo=minfo)
+    return TensorParallel(minfo.mesh, axis, append, write, bspec)
 
 
 def default_microbatches(cfg: ModelConfig, shape: InputShape,
@@ -131,9 +157,11 @@ def make_prefill_step(cfg: ModelConfig, minfo: shd.MeshInfo,
             and (shape.seq_len // 256) % minfo.model == 0):
         seq_axis = "model"
 
+    tp = kernel_tp(cfg, minfo, bspec, c_specs)
+
     def prefill_step(params, batch):
         return api.prefill(params, batch, cfg, capacity, bspec=bspec,
-                           seq_axis=seq_axis)
+                           seq_axis=seq_axis, tp=tp)
 
     fn = jax.jit(prefill_step,
                  in_shardings=(_named(minfo, p_specs), _named(minfo, b_specs)),
@@ -163,17 +191,18 @@ def make_decode_step(cfg: ModelConfig, minfo: shd.MeshInfo,
     logits_spec = P(shd.batch_spec_axes(minfo, B), None)
 
     bspec = shd.batch_spec_axes(minfo, B)
+    tp = kernel_tp(cfg, minfo, bspec, c_specs)
 
     def decode(params, cache, tokens, pos):
         if not sharded_append:
             return api.decode_step(params, cache, tokens, pos, cfg, bspec=bspec,
-                                   windowed=windowed_cache)
+                                   windowed=windowed_cache, tp=tp)
         # append-outside-scan + shard_map local write (§Perf): the cache is
         # read-only inside the layer scan; one O(token) write per group.
         from repro.distributed.cache_update import apply_cache_deltas
         logits, deltas = api.decode_step(params, cache, tokens, pos, cfg,
                                          bspec=bspec, windowed=windowed_cache,
-                                         return_deltas=True)
+                                         return_deltas=True, tp=tp)
         with jax.named_scope("cache_update"):
             new_cache = apply_cache_deltas(cache, deltas, pos, c_specs, minfo)
         return logits, new_cache

@@ -43,21 +43,21 @@ def train_loss(params, batch, cfg: ModelConfig, *, remat: bool = True,
 
 
 def prefill(params, batch, cfg: ModelConfig, capacity: int, bspec=None,
-            seq_axis=None):
+            seq_axis=None, tp=None):
     if _is_encdec(cfg):
         return encdec.prefill(params, batch, cfg, capacity, bspec=bspec)
     return transformer.prefill(params, batch, cfg, capacity, bspec=bspec,
-                               seq_axis=seq_axis)
+                               seq_axis=seq_axis, tp=tp)
 
 
 def decode_step(params, cache, tokens, pos, cfg: ModelConfig, bspec=None,
-                windowed: bool = False, return_deltas: bool = False):
+                windowed: bool = False, return_deltas: bool = False, tp=None):
     if _is_encdec(cfg):
         return encdec.decode_step(params, cache, tokens, pos, cfg, bspec=bspec,
                                   return_deltas=return_deltas)
     return transformer.decode_step(params, cache, tokens, pos, cfg, bspec=bspec,
                                    windowed=windowed,
-                                   return_deltas=return_deltas)
+                                   return_deltas=return_deltas, tp=tp)
 
 
 def init_cache(cfg: ModelConfig, batch: int, capacity: int,

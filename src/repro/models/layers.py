@@ -9,16 +9,41 @@ live memory — this is the pure-JAX oracle mirrored by the Pallas kernel in
 
 from __future__ import annotations
 
+import dataclasses
 import functools
-from typing import Optional
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import Mesh, PartitionSpec as P
 
 Array = jax.Array
 
 NEG_INF = -2.0 ** 30  # large-negative that survives bf16 softmax math in f32
+
+
+@dataclasses.dataclass(frozen=True)
+class TensorParallel:
+    """Where a step's tensors lie, for the attention kernels that run under
+    ``shard_map``, as the step builder hands it to the model: query heads
+    (and the projections around them) are split over the mesh axis
+    ``axis``, the decode cache's positions over the same axis, and the
+    batch over the mesh axes ``batch`` (None: replicated).  The cache's
+    writes come with it: ``append(c, d, pos)`` folds one token d (B, KV,
+    1, hd) into a shard's part c (B, KV, S / shards, hd) if its slot lies
+    there, and ``write(buf, kv, pos)`` writes a prefill's K/V (count, B,
+    KV, T, hd), whole on every shard, at positions [pos, pos + T) of the
+    split cache."""
+    mesh: Mesh
+    axis: str
+    append: Callable
+    write: Callable
+    batch: Optional[tuple] = None
+
+    @property
+    def shards(self) -> int:
+        return self.mesh.shape[self.axis]
 
 
 def constrain_batch(x: Array, bspec) -> Array:
@@ -56,6 +81,32 @@ def rmsnorm(x: Array, weight: Array, eps: float = 1e-6) -> Array:
     var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
     y = x32 * lax.rsqrt(var + eps)
     return (y * weight.astype(jnp.float32)).astype(dtype)
+
+
+def layernorm(x: Array, weight: Array, bias: Array, eps: float = 1e-5) -> Array:
+    dtype = x.dtype
+    x32 = x.astype(jnp.float32)
+    mu = jnp.mean(x32, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x32 - mu), axis=-1, keepdims=True)
+    y = (x32 - mu) * lax.rsqrt(var + eps)
+    return (y * weight.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(dtype)
+
+
+def norm(p: dict, name: str, x: Array, cfg) -> Array:
+    """The block's norm ``p[name]``: RMSNorm, or LayerNorm with the bias
+    ``p[name + "_bias"]``."""
+    if cfg.norm_type == "layernorm":
+        return layernorm(x, p[name], p[name + "_bias"], cfg.norm_eps)
+    return rmsnorm(x, p[name], cfg.norm_eps)
+
+
+def init_norm(cfg, name: str) -> dict:
+    dt = jnp.dtype(cfg.dtype)
+    p = {name: jnp.ones((cfg.d_model,), dt)}
+    if cfg.norm_type == "layernorm":
+        p[name + "_bias"] = jnp.zeros((cfg.d_model,), dt)
+    return p
 
 
 def softcap(x: Array, cap: Optional[float]) -> Array:
@@ -386,12 +437,14 @@ def init_attn_block(rng, cfg, *, cross: bool = False) -> dict:
         "wk": (jax.random.normal(ks[1], (d, KV, hd)) * std).astype(dt),
         "wv": (jax.random.normal(ks[2], (d, KV, hd)) * std).astype(dt),
         "wo": (jax.random.normal(ks[3], (H, hd, d)) * std).astype(dt),
-        "norm": jnp.ones((d,), dt),
+        **init_norm(cfg, "norm"),
     }
     if cfg.attention_bias:
         p["bq"] = jnp.zeros((H, hd), dt)
         p["bk"] = jnp.zeros((KV, hd), dt)
         p["bv"] = jnp.zeros((KV, hd), dt)
+    if cfg.attention_out_bias:
+        p["bo"] = jnp.zeros((d,), dt)
     if cfg.post_block_norm:
         p["post_norm"] = jnp.ones((d,), dt)
     if cross:
@@ -410,6 +463,73 @@ def qkv_proj(p: dict, x: Array, cfg) -> tuple[Array, Array, Array]:
     return q, k, v
 
 
+def shard_weights(m: Array, l: Array) -> Array:
+    """Weights that combine attention computed over n disjoint parts of the
+    keys: m, l (n, ...) are each part's running max of the scores and its
+    sum of exp(score - m), as the decode kernel returns them (m = -inf and
+    l = 0 for a part with no live position).  The combined output is the
+    sum over parts of weight * that part's normalised output
+    (log-sum-exp)."""
+    w = l * jnp.exp(m - m.max(axis=0))
+    return w / w.sum(axis=0)
+
+
+def cp_decode_attention(q, k_cache, v_cache, k_new, v_new, pos, *,
+                        tp: TensorParallel, window=None, logit_cap=None):
+    """Decode attention with the heads of q (B, H, hd) and the positions of
+    the cache (B, KV, S, hd) split over ``tp.axis``.  Each shard gathers
+    every head of q, folds the new token (B, KV, 1, hd) into its own part
+    of the cache if its slot lies there, and runs the decode kernel over
+    that part; the shards' partial outputs are combined by log-sum-exp in
+    one reduce-scatter over the heads, so that each shard ends with the
+    heads of its slice of the output projection.  Returns (B, H, hd),
+    heads split like q's."""
+    from repro.kernels.decode_attention.ops import decode_attention_kvmajor
+    axis = tp.axis
+
+    def local(q, kc, vc, k_new, v_new, pos):
+        with jax.named_scope("attn_combine"):
+            q = lax.all_gather(q, axis, axis=1, tiled=True)
+        with jax.named_scope("cache_update"):
+            kc, vc = tp.append(kc, k_new, pos), tp.append(vc, v_new, pos)
+        shard = lax.axis_index(axis)
+        o, m, l = decode_attention_kvmajor(
+            q, kc, vc, pos - shard * kc.shape[2], window=window,
+            logit_cap=logit_cap, stats=True)
+        with jax.named_scope("attn_combine"):
+            m, l = lax.all_gather(jnp.stack([m, l]), axis, axis=1)
+            w = shard_weights(m, l)[shard]
+            o = lax.psum_scatter(o.astype(jnp.float32) * w[..., None],
+                                 axis, scatter_dimension=1, tiled=True)
+        return o.astype(q.dtype)
+
+    heads = P(tp.batch, axis, None)
+    cache = P(tp.batch, None, axis, None)
+    new = P(tp.batch, None, None, None)
+    # check_vma off: a pallas_call's outputs carry no varying-axes record
+    return jax.shard_map(local, mesh=tp.mesh,
+                         in_specs=(heads, cache, cache, new, new, P()),
+                         out_specs=heads, check_vma=False)(
+        q, k_cache, v_cache, k_new, v_new, pos)
+
+
+def tp_flash_attention(q, k, v, *, tp: TensorParallel, window=None,
+                       logit_cap=None):
+    """The prefill's Pallas flash kernel with the heads of q (B, T, H, hd)
+    split over ``tp.axis``: each shard attends with its own query heads;
+    K/V heads are split alike when their count divides the shards, else
+    (one K/V head, MQA) every shard holds them whole."""
+    from repro.kernels.flash_attention.ops import flash_attention as pl_flash
+    heads = P(tp.batch, None, tp.axis, None)
+    kv = heads if k.shape[2] % tp.shards == 0 else P(tp.batch, None, None,
+                                                      None)
+    return jax.shard_map(
+        lambda q, k, v: pl_flash(q, k, v, causal=True, window=window,
+                                 logit_cap=logit_cap),
+        mesh=tp.mesh, in_specs=(heads, kv, kv), out_specs=heads,
+        check_vma=False)(q, k, v)
+
+
 @named_scope("attention")
 def attn_block_apply(
     p: dict,
@@ -424,6 +544,7 @@ def attn_block_apply(
     mode: str = "train",                 # train | prefill | decode
     ring: bool = False,                  # windowed ring-buffer cache (decode)
     seq_axis: Optional[str] = None,      # sequence-parallel attention (prefill)
+    tp: Optional[TensorParallel] = None,  # Pallas kernels under shard_map
 ):
     """Returns (y, new_kv) where new_kv is (k, v) for prefill, updated cache for
     decode, and None for train.
@@ -432,63 +553,70 @@ def attn_block_apply(
     ``window`` positions; the write slot is ``pos % capacity`` and attention
     reads the whole (unmasked) ring — valid once pos >= capacity-1, which the
     serving engine guarantees by prefilling ≥ window tokens.  Keys carry
-    absolute RoPE so ring order does not matter."""
+    absolute RoPE so ring order does not matter.
+
+    tp (with kernel_impl 'pallas'): the kernels run on each shard of a
+    tensor-parallel layout (``cp_decode_attention``,
+    ``tp_flash_attention``) instead of on arrays GSPMD would gather."""
     B, T, d = x.shape
-    h = rmsnorm(x, p["norm"], cfg.norm_eps)
+    h = norm(p, "norm", x, cfg)
     q, k, v = qkv_proj(p, h, cfg)
     if positions is None:
         positions = jnp.arange(T)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    if not cfg.learned_positions:
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
+    pallas = getattr(cfg, "kernel_impl", "xla") == "pallas"
 
     if mode == "decode":
         assert cache is not None and T == 1
         capacity = cache["k"].shape[2]          # (B, KV, S, hd)
-        k_new = k.transpose(0, 2, 1, 3)          # (B, KV, 1, hd)
-        v_new = v.transpose(0, 2, 1, 3)
-        if getattr(cfg, "kernel_impl", "xla") == "pallas" and not ring:
+        k_new = k.transpose(0, 2, 1, 3).astype(cache["k"].dtype)  # (B,KV,1,hd)
+        v_new = v.transpose(0, 2, 1, 3).astype(cache["v"].dtype)
+        new_kv = {"k": k_new, "v": v_new}
+        if pallas and not ring and tp is not None:
+            with jax.named_scope("cp_attention"):
+                o = cp_decode_attention(q[:, 0], cache["k"], cache["v"],
+                                        k_new, v_new, cache_pos, tp=tp,
+                                        window=window,
+                                        logit_cap=cfg.attn_logit_softcap)
+        elif pallas and not ring:
             # Pallas decode kernel (cache-only variant): fold the new token in
             # with a DUS, then run the blocked online-softmax kernel.
             from repro.kernels.decode_attention.ops import (
                 decode_attention_kvmajor)
             with jax.named_scope("cache_update"):
-                kc = lax.dynamic_update_slice_in_dim(
-                    cache["k"], k_new.astype(cache["k"].dtype), cache_pos,
-                    axis=2)
-                vc = lax.dynamic_update_slice_in_dim(
-                    cache["v"], v_new.astype(cache["v"].dtype), cache_pos,
-                    axis=2)
+                kc = lax.dynamic_update_slice_in_dim(cache["k"], k_new,
+                                                     cache_pos, axis=2)
+                vc = lax.dynamic_update_slice_in_dim(cache["v"], v_new,
+                                                     cache_pos, axis=2)
             o = decode_attention_kvmajor(q[:, 0], kc, vc, cache_pos,
                                          window=window,
                                          logit_cap=cfg.attn_logit_softcap)
-            o = o[:, None]
-            new_kv = {"k": k_new.astype(cache["k"].dtype),
-                      "v": v_new.astype(cache["v"].dtype)}
-            y = jnp.einsum("bthx,hxd->btd", o, p["wo"])
-            if cfg.post_block_norm:
-                y = rmsnorm(y, p["post_norm"], cfg.norm_eps)
-            return x + y, new_kv
-        # append-outside-scan: the cache is read-only here; the caller writes
-        # the returned (k_new, v_new) delta once per step (one stacked DUS
-        # outside the layer scan instead of a full cache rewrite per layer).
-        o = decode_attention(q[:, 0], cache["k"], cache["v"],
-                             jnp.asarray(capacity, jnp.int32) if ring
-                             else cache_pos,
-                             window=None if ring else window,
-                             logit_cap=cfg.attn_logit_softcap,
-                             k_new=k_new.astype(cache["k"].dtype),
-                             v_new=v_new.astype(cache["v"].dtype),
-                             exclude_slot=(cache_pos % capacity) if ring
-                             else None)
+        else:
+            # append-outside-scan: the cache is read-only here; the caller
+            # writes the returned (k_new, v_new) delta once per step (one
+            # stacked DUS outside the layer scan instead of a full cache
+            # rewrite per layer).
+            o = decode_attention(q[:, 0], cache["k"], cache["v"],
+                                 jnp.asarray(capacity, jnp.int32) if ring
+                                 else cache_pos,
+                                 window=None if ring else window,
+                                 logit_cap=cfg.attn_logit_softcap,
+                                 k_new=k_new, v_new=v_new,
+                                 exclude_slot=(cache_pos % capacity) if ring
+                                 else None)
         o = o[:, None]                            # (B, 1, H, hd)
-        new_kv = {"k": k_new.astype(cache["k"].dtype),
-                  "v": v_new.astype(cache["v"].dtype)}
-    elif (mode == "prefill" and getattr(cfg, "kernel_impl", "xla") == "pallas"
-          and causal):
+    elif mode == "prefill" and pallas and causal:
         # Pallas flash-attention kernel (interpret mode on CPU; TPU target)
-        from repro.kernels.flash_attention.ops import flash_attention as pl_flash
-        o = pl_flash(q, k, v, causal=True, window=window,
-                     logit_cap=cfg.attn_logit_softcap)
+        if tp is not None:
+            o = tp_flash_attention(q, k, v, tp=tp, window=window,
+                                   logit_cap=cfg.attn_logit_softcap)
+        else:
+            from repro.kernels.flash_attention.ops import (
+                flash_attention as pl_flash)
+            o = pl_flash(q, k, v, causal=True, window=window,
+                         logit_cap=cfg.attn_logit_softcap)
         new_kv = {"k": k, "v": v}
     else:
         o = flash_attention(q, k, v, causal=causal, window=window,
@@ -497,6 +625,8 @@ def attn_block_apply(
         new_kv = {"k": k, "v": v} if mode == "prefill" else None
 
     y = jnp.einsum("bthx,hxd->btd", o, p["wo"])
+    if cfg.attention_out_bias:
+        y = y + p["bo"]
     if cfg.post_block_norm:
         y = rmsnorm(y, p["post_norm"], cfg.norm_eps)
     return x + y, new_kv
@@ -524,7 +654,7 @@ def encode_kv(p: dict, enc_out: Array, cfg) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# SwiGLU MLP
+# MLP: SwiGLU (gate/up/down), or the plain GELU MLP with biases (GPT-BigCode)
 # ---------------------------------------------------------------------------
 def init_mlp(rng, cfg, d_ff: Optional[int] = None) -> dict:
     d = cfg.d_model
@@ -534,10 +664,14 @@ def init_mlp(rng, cfg, d_ff: Optional[int] = None) -> dict:
     dt = jnp.dtype(cfg.dtype)
     p = {
         "wi": (jax.random.normal(ks[0], (d, f)) * std).astype(dt),
-        "wg": (jax.random.normal(ks[1], (d, f)) * std).astype(dt),
         "wo": (jax.random.normal(ks[2], (f, d)) * std).astype(dt),
-        "norm": jnp.ones((d,), dt),
+        **init_norm(cfg, "norm"),
     }
+    if cfg.mlp_type == "gelu":
+        p["bi"] = jnp.zeros((f,), dt)
+        p["bo"] = jnp.zeros((d,), dt)
+    else:
+        p["wg"] = (jax.random.normal(ks[1], (d, f)) * std).astype(dt)
     if cfg.post_block_norm:
         p["post_norm"] = jnp.ones((d,), dt)
     return p
@@ -545,8 +679,12 @@ def init_mlp(rng, cfg, d_ff: Optional[int] = None) -> dict:
 
 @named_scope("mlp")
 def mlp_apply(p: dict, x: Array, cfg) -> Array:
-    h = rmsnorm(x, p["norm"], cfg.norm_eps)
-    y = (jax.nn.silu(h @ p["wg"]) * (h @ p["wi"])) @ p["wo"]
+    h = norm(p, "norm", x, cfg)
+    if cfg.mlp_type == "gelu":
+        y = jax.nn.gelu(h @ p["wi"] + p["bi"], approximate=True) @ p["wo"] \
+            + p["bo"]
+    else:
+        y = (jax.nn.silu(h @ p["wg"]) * (h @ p["wi"])) @ p["wo"]
     if cfg.post_block_norm:
         y = rmsnorm(y, p["post_norm"], cfg.norm_eps)
     return x + y

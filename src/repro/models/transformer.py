@@ -72,10 +72,14 @@ def init_params(rng, cfg) -> dict:
     params = {
         "embed": (jax.random.normal(keys[0], (cfg.vocab_size, cfg.d_model)) * 0.02
                   ).astype(dt),
-        "final_norm": jnp.ones((cfg.d_model,), dt),
+        **L.init_norm(cfg, "final_norm"),
         "groups": [init_group(k, cfg, kind, count)
                    for k, (kind, count) in zip(keys[1:], cfg.layer_groups)],
     }
+    if cfg.learned_positions:
+        params["pos_embed"] = (jax.random.normal(
+            jax.random.fold_in(keys[0], 1),
+            (cfg.learned_positions, cfg.d_model)) * 0.02).astype(dt)
     if not cfg.tie_embeddings:
         params["lm_head"] = (jax.random.normal(keys[-2], (cfg.d_model, cfg.vocab_size))
                              * 0.02).astype(dt)
@@ -89,11 +93,11 @@ def init_params(rng, cfg) -> dict:
 # Layer application
 # ---------------------------------------------------------------------------
 def dense_layer_apply(lp, x, cfg, *, window, mode, kv=None, cache_pos=None,
-                      positions=None, ring=False, seq_axis=None):
+                      positions=None, ring=False, seq_axis=None, tp=None):
     x, new_kv = L.attn_block_apply(
         lp["attn"], x, cfg, window=window, mode=mode, cache=kv,
         cache_pos=cache_pos, positions=positions, ring=ring,
-        seq_axis=seq_axis)
+        seq_axis=seq_axis, tp=tp)
     if "moe" in lp:
         x, aux = MOE.moe_block_apply(lp["moe"], x, cfg)
     else:
@@ -218,14 +222,17 @@ def run_group_train(gp, x, cfg, kind, *, positions, remat=False, bspec=None):
 
 
 def run_group_prefill(gp, x, cfg, kind, cache, *, positions, cache_pos=0,
-                      seq_axis=None):
-    """Forward with cache write-back at [cache_pos, cache_pos+T)."""
+                      seq_axis=None, tp=None):
+    """Forward with cache write-back at [cache_pos, cache_pos+T).
+    tp: the Pallas kernels' tensor-parallel layout (``L.TensorParallel``)."""
     window = cfg.sliding_window
     T = x.shape[1]
 
     @L.named_scope("cache_update")
     def put(buf, kv):  # buf (count,B,KV,cap,hd); kv (count,B,T,KV,hd)
         kv = kv.transpose(0, 1, 3, 2, 4)         # -> (count,B,KV,T,hd)
+        if tp is not None:  # each shard writes its own positions
+            return tp.write(buf, kv, jnp.asarray(cache_pos, jnp.int32))
         return lax.dynamic_update_slice_in_dim(buf, kv.astype(buf.dtype),
                                                cache_pos, axis=3)
 
@@ -233,7 +240,7 @@ def run_group_prefill(gp, x, cfg, kind, cache, *, positions, cache_pos=0,
         def body(carry, lp):
             y, kv, aux = dense_layer_apply(lp, carry, cfg, window=_window(cfg, kind),
                                            mode="prefill", positions=positions,
-                                           seq_axis=seq_axis)
+                                           seq_axis=seq_axis, tp=tp)
             return y, (kv["k"], kv["v"], aux)
         with jax.named_scope("layers"):
             x, (ks, vs, auxs) = lax.scan(body, x, gp)
@@ -293,9 +300,10 @@ def run_group_prefill(gp, x, cfg, kind, cache, *, positions, cache_pos=0,
 
 
 def run_group_decode(gp, x, cfg, kind, cache, *, pos, windowed=False,
-                     return_deltas=False):
+                     return_deltas=False, tp=None):
     """One-token step.  pos: scalar int32 — index where the new token lands.
     windowed=True: sliding-window layers use ring-buffer caches.
+    tp: the Pallas kernels' tensor-parallel layout (``L.TensorParallel``).
 
     Attention bodies read the cache and emit (k_new, v_new) deltas; the cache
     is written back with ONE stacked dynamic-update-slice per group after the
@@ -321,7 +329,7 @@ def run_group_decode(gp, x, cfg, kind, cache, *, pos, windowed=False,
             y, kv, _ = dense_layer_apply(lp, carry, cfg, window=_window(cfg, kind),
                                          mode="decode", kv={"k": k_l, "v": v_l},
                                          cache_pos=pos, positions=positions,
-                                         ring=ring)
+                                         ring=ring, tp=tp)
             return y, (kv["k"], kv["v"])
         with jax.named_scope("layers"):
             x, (dk, dv) = lax.scan(body, x, (gp, cache["k"], cache["v"]))
@@ -388,7 +396,9 @@ def run_group_decode(gp, x, cfg, kind, cache, *, pos, windowed=False,
 # Embedding / head / loss
 # ---------------------------------------------------------------------------
 @L.named_scope("embed")
-def embed_tokens(params, tokens, cfg, patch_embeds=None):
+def embed_tokens(params, tokens, cfg, patch_embeds=None, positions=None):
+    """positions: the tokens' absolute positions (T,), for a learned
+    position table (``cfg.learned_positions``)."""
     x = params["embed"][tokens].astype(jnp.dtype(cfg.dtype))
     if cfg.scale_embeddings:
         x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
@@ -397,6 +407,8 @@ def embed_tokens(params, tokens, cfg, patch_embeds=None):
         if "vis_proj" in params:
             pe = pe @ params["vis_proj"]
         x = jnp.concatenate([pe, x], axis=1)
+    if cfg.learned_positions:
+        x = x + params["pos_embed"][positions].astype(x.dtype)
     return x
 
 
@@ -452,7 +464,7 @@ def forward_full(params, x, cfg, *, mode, positions, remat=False, bspec=None):
         x, aux = run_group_train(gp, x, cfg, kind, positions=positions,
                                  remat=remat, bspec=bspec)
         aux_total = aux_total + aux
-    x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    x = L.norm(params, "final_norm", x, cfg)
     return x, aux_total
 
 
@@ -463,10 +475,10 @@ def train_loss(params, batch, cfg, *, remat=True, bspec=None):
     """
     tokens = batch["tokens"]
     patches = batch.get("patch_embeds")
-    x = L.constrain_batch(embed_tokens(params, tokens, cfg, patch_embeds=patches),
-                          bspec)
-    B, T = x.shape[0], x.shape[1]
+    T = tokens.shape[1] + (0 if patches is None else patches.shape[1])
     positions = jnp.arange(T)
+    x = L.constrain_batch(embed_tokens(params, tokens, cfg, patch_embeds=patches,
+                                       positions=positions), bspec)
     h, aux = forward_full(params, x, cfg, mode="train", positions=positions,
                           remat=remat, bspec=bspec)
     n_text = tokens.shape[1]
@@ -478,39 +490,42 @@ def train_loss(params, batch, cfg, *, remat=True, bspec=None):
     return loss, {"ce": ce, "aux": aux}
 
 
-def prefill(params, batch, cfg, capacity: int, bspec=None, seq_axis=None):
+def prefill(params, batch, cfg, capacity: int, bspec=None, seq_axis=None,
+            tp=None):
     """Returns (last_logits (B,V) f32, cache) with cache capacity ``capacity``."""
     tokens = batch["tokens"]
     patches = batch.get("patch_embeds")
-    x = L.constrain_batch(embed_tokens(params, tokens, cfg, patch_embeds=patches),
-                          bspec)
-    B, T = x.shape[0], x.shape[1]
+    B = tokens.shape[0]
+    T = tokens.shape[1] + (0 if patches is None else patches.shape[1])
     positions = jnp.arange(T)
+    x = L.constrain_batch(embed_tokens(params, tokens, cfg, patch_embeds=patches,
+                                       positions=positions), bspec)
     cache = init_cache(cfg, B, capacity)
     new_cache = []
     for gp, c, (kind, count) in zip(params["groups"], cache, cfg.layer_groups):
         x, nc, _ = run_group_prefill(gp, x, cfg, kind, c, positions=positions,
-                                     seq_axis=seq_axis)
+                                     seq_axis=seq_axis, tp=tp)
         new_cache.append(nc)
     with jax.named_scope("logits"):
-        x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        x = L.norm(params, "final_norm", x, cfg)
         logits = logits_last(params, x[:, -1], cfg)
     return logits, new_cache
 
 
 def decode_step(params, cache, tokens, pos, cfg, bspec=None, windowed=False,
-                return_deltas=False):
+                return_deltas=False, tp=None):
     """tokens: (B,) int32 new token ids; pos: scalar int32 slot index.
 
     Returns (logits (B,V) f32, new_cache) — or, with return_deltas, the
     per-group K/V deltas for a sharded append (distributed.cache_update)."""
-    x = L.constrain_batch(embed_tokens(params, tokens[:, None], cfg), bspec)
+    x = L.constrain_batch(embed_tokens(params, tokens[:, None], cfg,
+                                       positions=pos[None]), bspec)
     new_cache = []
     for gp, c, (kind, count) in zip(params["groups"], cache, cfg.layer_groups):
         x, nc = run_group_decode(gp, x, cfg, kind, c, pos=pos, windowed=windowed,
-                                 return_deltas=return_deltas)
+                                 return_deltas=return_deltas, tp=tp)
         new_cache.append(nc)
     with jax.named_scope("logits"):
-        x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        x = L.norm(params, "final_norm", x, cfg)
         logits = logits_last(params, x[:, 0], cfg)
     return logits, new_cache

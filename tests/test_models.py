@@ -57,7 +57,8 @@ def test_smoke_prefill_decode_shapes(arch, rng):
 
 
 @pytest.mark.parametrize("arch", ["smollm_360m", "gemma2_2b", "mamba2_1p3b",
-                                  "zamba2_1p2b", "mixtral_8x22b"])
+                                  "zamba2_1p2b", "mixtral_8x22b",
+                                  "granite_20b"])
 def test_decode_matches_prefill(arch, rng):
     """Prefilling [t0..tN] must equal prefilling [t0..tN-1] then decoding tN."""
     cfg = get_config(arch, tiny=True)
@@ -117,7 +118,7 @@ def test_moe_aux_loss_and_capacity():
 
 
 @pytest.mark.parametrize("arch", ["smollm_360m", "mixtral_8x22b",
-                                  "mamba2_1p3b", "gemma2_2b"])
+                                  "mamba2_1p3b", "gemma2_2b", "granite_20b"])
 def test_pallas_kernel_path_matches_xla(arch, rng):
     """kernel_impl='pallas' (interpret mode on CPU) must reproduce the XLA
     path end-to-end: prefill logits and one decode step."""

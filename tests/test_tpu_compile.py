@@ -10,17 +10,20 @@ time may load the TPU library, and it keeps it until it exits.
 
 import contextlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs.base import get_config
+from repro.configs.base import InputShape, get_config
 from repro.models import api
 
 SMOLLM = get_config("smollm-360m")
 MAMBA2 = get_config("mamba2-1.3b")
+GRANITE = get_config("granite-20b").replace(kernel_impl="pallas")
 
 
 @pytest.fixture(scope="module")
@@ -166,3 +169,57 @@ def test_smollm_prefill_step_compiles_with_kernels(one_chip, monkeypatch):
     batch = {"tokens": _spec(one_chip, (B, T), jnp.int32)}
     _assert_kernel(_compile(
         lambda p, b: api.prefill(p, b, cfg, 2 * T), params, batch))
+
+
+# granite-20b.decode-tp4's shapes: batch 64, prompt 512, 1536 positions
+TP4_B, TP4_P, TP4_C = 64, 512, 1536
+
+
+@pytest.fixture(scope="module")
+def granite_tp4(topo):
+    """granite-20b's prefill and decode programs at published widths,
+    tensor-parallel over the four chips of the described 2x2 host, built by
+    the program's step builders and compiled once for the tests below."""
+    from jax.sharding import AxisType, Mesh
+    from repro.distributed.sharding import MeshInfo
+    from repro.launch import steps
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    minfo = MeshInfo(mesh)
+    out = {}
+    with pytest.MonkeyPatch.context() as mp, mesh, _no_persistent_cache():
+        _on_chip(mp)
+        fn, args, _, _ = steps.make_prefill_step(
+            GRANITE, minfo, InputShape("p", TP4_P, TP4_B, "prefill"),
+            capacity=TP4_C)
+        out["prefill"] = fn.lower(*args).compile()
+        fn, args, _, _ = steps.make_decode_step(
+            GRANITE, minfo, InputShape("d", TP4_C, TP4_B, "decode"))
+        out["decode"] = fn.lower(*args).compile()
+    return out
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_granite_tp4_steps_compile_with_kernels(granite_tp4, program):
+    """Both kernels run inside the programs on every chip, and a chip holds
+    a program's arguments, outputs and temporaries in its 16 GiB."""
+    compiled = granite_tp4[program]
+    _assert_kernel(compiled)
+    ma = compiled.memory_analysis()
+    held = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    assert held < 16 * 2 ** 30, held
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_granite_tp4_gathers_no_cache(granite_tp4, program):
+    """No all-gather in either program is as large as one layer's K cache
+    over all four chips: each chip attends over, and writes, its own
+    positions of the cache."""
+    cfg = GRANITE
+    one_layer = TP4_B * cfg.num_kv_heads * TP4_C * cfg.head_dim
+    gathered = [int(np.prod([int(d) for d in dims.split(",") if d]))
+                for dims in re.findall(
+                    r"= \w+\[([\d,]*)\]\{[^}]*\} all-gather(?:-start)?\(",
+                    granite_tp4[program].as_text())]
+    assert gathered and max(gathered) < one_layer, gathered
