@@ -53,6 +53,21 @@ def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
+def _sublanes(dtype) -> int:
+    """Rows of one (sublane, 128-lane) VMEM or HBM tile of ``dtype``."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def cache_is_s_minor(S: int, hd: int, dtype) -> bool:
+    """Whether a TPU lays a (..., S, hd) cache out with its positions minor.
+    Of the two minor orders the TPU takes the one whose (sublane, 128)
+    tiles pad less, hd minor on a tie: hd 64 lies S-minor, hd 128 hd-minor.
+    The kernel reads the view that matches, so no relayout is made."""
+    sub = _sublanes(dtype)
+    return (_round_up(hd, sub) * _round_up(S, 128)
+            < _round_up(S, sub) * _round_up(hd, 128))
+
+
 def decode_tiling(BKV: int, S: int, G: int, hd: int, dtype, *,
                   rows: Optional[int] = None,
                   block_k: Optional[int] = None) -> DecodeTiling:
@@ -62,23 +77,28 @@ def decode_tiling(BKV: int, S: int, G: int, hd: int, dtype, *,
     that does.  Otherwise block_k is the largest legal tile up to
     MAX_BLOCK_K, then rows the fewest that move STEP_BYTES within the
     autotuner's VMEM budget; when all BKV rows move less, block_k grows
-    towards S."""
+    towards S.  A legal tile is whole (sublane, 128) tiles of the cache's
+    layout (``cache_is_s_minor``), or the whole of S."""
     sz = jnp.dtype(dtype).itemsize
     budget = autotune.VMEM_BYTES
-    sub = 8 * max(1, 4 // sz)                 # sublanes of one VMEM tile
+    sub = _sublanes(dtype)
     lanes = _round_up(hd, 128)                # hd 64 fills half the lanes
+    s_minor = cache_is_s_minor(S, hd, dtype)
 
     def step_bytes(r, bk):
         return 2 * r * bk * hd * sz
 
     def vmem(r, bk):
-        blocks = 2 * 2 * r * (_round_up(bk, sub) + _round_up(G, sub)) \
-            * lanes * sz                      # K, V, q, o; two buffers each
+        kv = (_round_up(hd, sub) * _round_up(bk, 128) if s_minor
+              else _round_up(bk, sub) * lanes)
+        rows_io = (2 * _round_up(G, sub) + 2 * sub) * lanes  # q, o, new K/V
+        blocks = 2 * r * (2 * kv + rows_io) * sz      # two buffers each
         f32 = 4 * r * _round_up(G, 8) * (2 * 128 + lanes
                                          + 3 * _round_up(bk, 128))
         return blocks + f32
 
-    tiles = [d for d in _divisors(S) if d % sub == 0] or [S]
+    unit = 128 if s_minor else sub
+    tiles = [d for d in _divisors(S) if d % unit == 0] or [S]
     if block_k is not None:
         bk = max([d for d in tiles if d <= block_k] or [min(tiles)])
     else:
@@ -107,63 +127,16 @@ def _resolve_tiling(rows, block_k, dtype, BKV, G, hd, S) -> DecodeTiling:
     return decode_tiling(BKV, S, G, hd, dtype, rows=rows, block_k=block_k)
 
 
-def _vmem_limit(BKV, S, G, hd, dtype, rows, block_k) -> int:
-    t = decode_tiling(BKV, S, G, hd, dtype, rows=rows, block_k=block_k)
-    return max(autotune.VMEM_BYTES, 2 * t.vmem_bytes)
-
-
 def decode_attention(
     q: jax.Array,        # (B, H, hd)
     k_cache: jax.Array,  # (B, S, KV, hd)
     v_cache: jax.Array,  # (B, S, KV, hd)
     pos,                 # scalar int32
-    *,
-    window: Optional[int] = None,
-    logit_cap: Optional[float] = None,
-    rows: Optional[int] = None,
-    block_k: Optional[int] = None,
-    interpret: Optional[bool] = None,
+    **kw,
 ) -> jax.Array:
-    t = _resolve_tiling(rows, block_k, q.dtype, q.shape[0] * k_cache.shape[2],
-                        q.shape[1] // k_cache.shape[2], q.shape[2],
-                        k_cache.shape[1])
-    if interpret is None:
-        interpret = _on_cpu()
-    return _decode_attention(q, k_cache, v_cache, pos, window=window,
-                             logit_cap=logit_cap, rows=t.rows,
-                             block_k=t.block_k, interpret=interpret)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("window", "logit_cap", "rows", "block_k",
-                              "interpret"))
-def _decode_attention(
-    q: jax.Array,
-    k_cache: jax.Array,
-    v_cache: jax.Array,
-    pos,
-    *,
-    window: Optional[int],
-    logit_cap: Optional[float],
-    rows: int,
-    block_k: int,
-    interpret: bool,
-) -> jax.Array:
-    B, H, hd = q.shape
-    _, S, KV, _ = k_cache.shape
-    G = H // KV
-    q3 = q.reshape(B, KV, G, hd).reshape(B * KV, G, hd)
-    k3 = k_cache.transpose(0, 2, 1, 3).reshape(B * KV, S, hd)
-    v3 = v_cache.transpose(0, 2, 1, 3).reshape(B * KV, S, hd)
-    pos_arr = jnp.asarray(pos, jnp.int32).reshape(1)
-
-    out = decode_attention_fwd(q3, k3, v3, pos_arr, window=window,
-                               logit_cap=logit_cap, rows=rows,
-                               block_k=block_k,
-                               vmem_limit_bytes=_vmem_limit(
-                                   B * KV, S, G, hd, q.dtype, rows, block_k),
-                               interpret=interpret)
-    return out.reshape(B, KV, G, hd).reshape(B, H, hd)
+    """``decode_attention_kvmajor`` over a (B, S, KV, hd) cache."""
+    return decode_attention_kvmajor(q, k_cache.transpose(0, 2, 1, 3),
+                                    v_cache.transpose(0, 2, 1, 3), pos, **kw)
 
 
 def decode_attention_kvmajor(
@@ -171,7 +144,22 @@ def decode_attention_kvmajor(
     k_cache: jax.Array,  # (B, KV, S, hd) — the model's attention-native layout
     v_cache: jax.Array,
     pos,
+    **kw,
+):
+    """``decode_attention_stacked`` over one layer's cache."""
+    return decode_attention_stacked(q, k_cache[None], v_cache[None], pos, 0,
+                                    **kw)
+
+
+def decode_attention_stacked(
+    q: jax.Array,        # (B, H, hd)
+    k_stack: jax.Array,  # (L, B, KV, S, hd): a layer group's stacked cache
+    v_stack: jax.Array,
+    pos,                 # scalar int32: the new token's position
+    layer,               # scalar int32: the layer of the stack to read
     *,
+    k_new: Optional[jax.Array] = None,   # (B, KV, 1, hd)
+    v_new: Optional[jax.Array] = None,
     window=None,
     logit_cap=None,
     rows: Optional[int] = None,
@@ -179,54 +167,52 @@ def decode_attention_kvmajor(
     interpret=None,
     stats: bool = False,
 ):
-    """Like decode_attention but takes the (B, KV, S, hd) cache layout the
-    model uses — a pure reshape, no transpose.
+    """Attention of one new token per sequence over layer ``layer`` of a
+    stacked cache, which the kernel reads in place.  With ``k_new`` and
+    ``v_new`` the new token's K/V come apart and the cache's slot ``pos``
+    is not read (it is written after the step); without them the cache
+    holds that slot.
 
     ``pos`` may lie outside [0, S): one shard of a cache split by
     positions, with the token's own position counted from the shard's
     first.  With ``stats`` returns (o, m, l): m, l (B, H) float32 are the
     softmax's running max of the scaled scores and its sum of exp(score -
     m), with m = -inf and l = 0 (and o = 0) where no position is live."""
-    t = _resolve_tiling(rows, block_k, q.dtype, q.shape[0] * k_cache.shape[1],
-                        q.shape[1] // k_cache.shape[1], q.shape[2],
-                        k_cache.shape[2])
+    t = _resolve_tiling(rows, block_k, q.dtype, q.shape[0] * k_stack.shape[2],
+                        q.shape[1] // k_stack.shape[2], q.shape[2],
+                        k_stack.shape[3])
     if interpret is None:
         interpret = _on_cpu()
-    return _decode_attention_kvmajor(q, k_cache, v_cache, pos, window=window,
-                                     logit_cap=logit_cap, rows=t.rows,
-                                     block_k=t.block_k, interpret=interpret,
-                                     stats=stats)
+    return _decode_attention_stacked(
+        q, k_stack, v_stack, pos, layer, k_new, v_new, window=window,
+        logit_cap=logit_cap, tiling=t, interpret=interpret, stats=stats)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("window", "logit_cap", "rows", "block_k",
-                              "interpret", "stats"))
-def _decode_attention_kvmajor(
-    q: jax.Array,
-    k_cache: jax.Array,
-    v_cache: jax.Array,
-    pos,
-    *,
-    window,
-    logit_cap,
-    rows: int,
-    block_k: int,
-    interpret: bool,
-    stats: bool,
-):
+    jax.jit, static_argnames=("window", "logit_cap", "tiling", "interpret",
+                              "stats"))
+def _decode_attention_stacked(q, k_stack, v_stack, pos, layer, k_new, v_new,
+                              *, window, logit_cap, tiling: DecodeTiling,
+                              interpret: bool, stats: bool):
     B, H, hd = q.shape
-    _, KV, S, _ = k_cache.shape
+    L, _, KV, S, _ = k_stack.shape
     G = H // KV
-    q3 = q.reshape(B * KV, G, hd)
-    k3 = k_cache.reshape(B * KV, S, hd)
-    v3 = v_cache.reshape(B * KV, S, hd)
-    pos_arr = jnp.asarray(pos, jnp.int32).reshape(1)
-    out = decode_attention_fwd(q3, k3, v3, pos_arr, window=window,
-                               logit_cap=logit_cap, rows=rows,
-                               block_k=block_k,
-                               vmem_limit_bytes=_vmem_limit(
-                                   B * KV, S, G, hd, q.dtype, rows, block_k),
-                               interpret=interpret, stats=stats)
+    s_minor = cache_is_s_minor(S, hd, k_stack.dtype)
+
+    def view(c):                  # the bytes as the chip lays them out
+        c = jnp.swapaxes(c, 3, 4) if s_minor else c
+        return c.reshape(L, B * KV, *c.shape[3:])
+
+    new = () if k_new is None else (k_new.reshape(B * KV, 1, hd),
+                                    v_new.reshape(B * KV, 1, hd))
+    out = decode_attention_fwd(
+        q.reshape(B * KV, G, hd), view(k_stack), view(v_stack),
+        jnp.asarray(pos, jnp.int32).reshape(1),
+        jnp.asarray(layer, jnp.int32).reshape(1), *new, s_minor=s_minor,
+        window=window, logit_cap=logit_cap, rows=tiling.rows,
+        block_k=tiling.block_k,
+        vmem_limit_bytes=max(autotune.VMEM_BYTES, 2 * tiling.vmem_bytes),
+        interpret=interpret, stats=stats)
     if not stats:
         return out.reshape(B, H, hd)
     out, st = out

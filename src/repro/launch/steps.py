@@ -33,8 +33,8 @@ def kernel_tp(cfg: ModelConfig, minfo: shd.MeshInfo, bspec,
               cache_specs=None) -> Optional[TensorParallel]:
     """The tensor-parallel layout under which the Pallas attention kernels
     run on each shard (``layers.cp_decode_attention``,
-    ``layers.tp_flash_attention``), with the cache writes of that layout
-    (``distributed.cache_update``), or None where they run whole: one
+    ``layers.tp_flash_attention``), with the prefill's cache write of that
+    layout (``distributed.cache_update``), or None where they run whole: one
     shard, XLA attention, heads that do not split over the model axis, or
     a K/V cache whose positions are not split over exactly that axis."""
     axis = "model"
@@ -45,13 +45,11 @@ def kernel_tp(cfg: ModelConfig, minfo: shd.MeshInfo, bspec,
                              is_leaf=lambda x: isinstance(x, P)):
         if s[-2] not in (axis, (axis,)):
             return None
-    append = functools.partial(cache_update.append_local, seq_axes=(axis,),
-                               mesh_axis_sizes=minfo.axis_sizes, axis=2)
     # the prefill's stacked K/V: (count, B, KV, T, hd)
     write = functools.partial(cache_update.write_kv,
                               spec=P(None, bspec, None, axis, None),
                               minfo=minfo)
-    return TensorParallel(minfo.mesh, axis, append, write, bspec)
+    return TensorParallel(minfo.mesh, axis, write, bspec)
 
 
 def default_microbatches(cfg: ModelConfig, shape: InputShape,

@@ -29,15 +29,12 @@ class TensorParallel:
     ``shard_map``, as the step builder hands it to the model: query heads
     (and the projections around them) are split over the mesh axis
     ``axis``, the decode cache's positions over the same axis, and the
-    batch over the mesh axes ``batch`` (None: replicated).  The cache's
-    writes come with it: ``append(c, d, pos)`` folds one token d (B, KV,
-    1, hd) into a shard's part c (B, KV, S / shards, hd) if its slot lies
-    there, and ``write(buf, kv, pos)`` writes a prefill's K/V (count, B,
-    KV, T, hd), whole on every shard, at positions [pos, pos + T) of the
-    split cache."""
+    batch over the mesh axes ``batch`` (None: replicated).  The prefill's
+    cache write comes with it: ``write(buf, kv, pos)`` writes a prefill's
+    K/V (count, B, KV, T, hd), whole on every shard, at positions [pos,
+    pos + T) of the split cache."""
     mesh: Mesh
     axis: str
-    append: Callable
     write: Callable
     batch: Optional[tuple] = None
 
@@ -474,28 +471,26 @@ def shard_weights(m: Array, l: Array) -> Array:
     return w / w.sum(axis=0)
 
 
-def cp_decode_attention(q, k_cache, v_cache, k_new, v_new, pos, *,
+def cp_decode_attention(q, k_stack, v_stack, k_new, v_new, pos, layer, *,
                         tp: TensorParallel, window=None, logit_cap=None):
     """Decode attention with the heads of q (B, H, hd) and the positions of
-    the cache (B, KV, S, hd) split over ``tp.axis``.  Each shard gathers
-    every head of q, folds the new token (B, KV, 1, hd) into its own part
-    of the cache if its slot lies there, and runs the decode kernel over
-    that part; the shards' partial outputs are combined by log-sum-exp in
-    one reduce-scatter over the heads, so that each shard ends with the
-    heads of its slice of the output projection.  Returns (B, H, hd),
-    heads split like q's."""
-    from repro.kernels.decode_attention.ops import decode_attention_kvmajor
+    the stacked cache (L, B, KV, S, hd) split over ``tp.axis``.  Each shard
+    gathers every head of q and runs the decode kernel over layer
+    ``layer`` of its own part of the stack, the new token (B, KV, 1, hd)
+    counted on the shard where its slot lies; the shards' partial outputs
+    are combined by log-sum-exp in one reduce-scatter over the heads, so
+    that each shard ends with the heads of its slice of the output
+    projection.  Returns (B, H, hd), heads split like q's."""
+    from repro.kernels.decode_attention.ops import decode_attention_stacked
     axis = tp.axis
 
-    def local(q, kc, vc, k_new, v_new, pos):
+    def local(q, kc, vc, k_new, v_new, pos, layer):
         with jax.named_scope("attn_combine"):
             q = lax.all_gather(q, axis, axis=1, tiled=True)
-        with jax.named_scope("cache_update"):
-            kc, vc = tp.append(kc, k_new, pos), tp.append(vc, v_new, pos)
         shard = lax.axis_index(axis)
-        o, m, l = decode_attention_kvmajor(
-            q, kc, vc, pos - shard * kc.shape[2], window=window,
-            logit_cap=logit_cap, stats=True)
+        o, m, l = decode_attention_stacked(
+            q, kc, vc, pos - shard * kc.shape[3], layer, k_new=k_new,
+            v_new=v_new, window=window, logit_cap=logit_cap, stats=True)
         with jax.named_scope("attn_combine"):
             m, l = lax.all_gather(jnp.stack([m, l]), axis, axis=1)
             w = shard_weights(m, l)[shard]
@@ -504,13 +499,13 @@ def cp_decode_attention(q, k_cache, v_cache, k_new, v_new, pos, *,
         return o.astype(q.dtype)
 
     heads = P(tp.batch, axis, None)
-    cache = P(tp.batch, None, axis, None)
+    cache = P(None, tp.batch, None, axis, None)
     new = P(tp.batch, None, None, None)
     # check_vma off: a pallas_call's outputs carry no varying-axes record
     return jax.shard_map(local, mesh=tp.mesh,
-                         in_specs=(heads, cache, cache, new, new, P()),
+                         in_specs=(heads, cache, cache, new, new, P(), P()),
                          out_specs=heads, check_vma=False)(
-        q, k_cache, v_cache, k_new, v_new, pos)
+        q, k_stack, v_stack, k_new, v_new, pos, layer)
 
 
 def tp_flash_attention(q, k, v, *, tp: TensorParallel, window=None,
@@ -539,8 +534,9 @@ def attn_block_apply(
     window: Optional[int] = None,
     causal: bool = True,
     positions: Optional[Array] = None,   # (T,) absolute positions
-    cache: Optional[dict] = None,        # {'k','v'}: (B, S, KV, hd) — decode only
+    cache: Optional[dict] = None,        # {'k','v'}: (B, KV, S, hd) — decode only
     cache_pos: Optional[Array] = None,   # scalar int32
+    layer: Optional[Array] = None,       # cache is the stack; read this layer
     mode: str = "train",                 # train | prefill | decode
     ring: bool = False,                  # windowed ring-buffer cache (decode)
     seq_axis: Optional[str] = None,      # sequence-parallel attention (prefill)
@@ -554,6 +550,9 @@ def attn_block_apply(
     reads the whole (unmasked) ring — valid once pos >= capacity-1, which the
     serving engine guarantees by prefilling ≥ window tokens.  Keys carry
     absolute RoPE so ring order does not matter.
+
+    layer (Pallas decode, not ring): ``cache`` is the group's stacked cache
+    (L, B, KV, S, hd) and the kernel reads this layer of it in place.
 
     tp (with kernel_impl 'pallas'): the kernels run on each shard of a
     tensor-parallel layout (``cp_decode_attention``,
@@ -570,29 +569,29 @@ def attn_block_apply(
 
     if mode == "decode":
         assert cache is not None and T == 1
-        capacity = cache["k"].shape[2]          # (B, KV, S, hd)
+        capacity = cache["k"].shape[-2]         # (B, KV, S, hd)
         k_new = k.transpose(0, 2, 1, 3).astype(cache["k"].dtype)  # (B,KV,1,hd)
         v_new = v.transpose(0, 2, 1, 3).astype(cache["v"].dtype)
         new_kv = {"k": k_new, "v": v_new}
-        if pallas and not ring and tp is not None:
-            with jax.named_scope("cp_attention"):
-                o = cp_decode_attention(q[:, 0], cache["k"], cache["v"],
-                                        k_new, v_new, cache_pos, tp=tp,
-                                        window=window,
-                                        logit_cap=cfg.attn_logit_softcap)
-        elif pallas and not ring:
-            # Pallas decode kernel (cache-only variant): fold the new token in
-            # with a DUS, then run the blocked online-softmax kernel.
+        if pallas and not ring:
+            # the kernel reads the cache in place, the new token apart: the
+            # cache stays read-only, as on the XLA path
             from repro.kernels.decode_attention.ops import (
-                decode_attention_kvmajor)
-            with jax.named_scope("cache_update"):
-                kc = lax.dynamic_update_slice_in_dim(cache["k"], k_new,
-                                                     cache_pos, axis=2)
-                vc = lax.dynamic_update_slice_in_dim(cache["v"], v_new,
-                                                     cache_pos, axis=2)
-            o = decode_attention_kvmajor(q[:, 0], kc, vc, cache_pos,
-                                         window=window,
-                                         logit_cap=cfg.attn_logit_softcap)
+                decode_attention_stacked)
+            ks, vs = cache["k"], cache["v"]
+            if layer is None:
+                ks, vs, layer = ks[None], vs[None], 0
+            if tp is not None:
+                with jax.named_scope("cp_attention"):
+                    o = cp_decode_attention(q[:, 0], ks, vs, k_new, v_new,
+                                            cache_pos, layer, tp=tp,
+                                            window=window,
+                                            logit_cap=cfg.attn_logit_softcap)
+            else:
+                o = decode_attention_stacked(q[:, 0], ks, vs, cache_pos,
+                                             layer, k_new=k_new, v_new=v_new,
+                                             window=window,
+                                             logit_cap=cfg.attn_logit_softcap)
         else:
             # append-outside-scan: the cache is read-only here; the caller
             # writes the returned (k_new, v_new) delta once per step (one

@@ -93,10 +93,11 @@ def init_params(rng, cfg) -> dict:
 # Layer application
 # ---------------------------------------------------------------------------
 def dense_layer_apply(lp, x, cfg, *, window, mode, kv=None, cache_pos=None,
-                      positions=None, ring=False, seq_axis=None, tp=None):
+                      positions=None, ring=False, seq_axis=None, tp=None,
+                      layer=None):
     x, new_kv = L.attn_block_apply(
         lp["attn"], x, cfg, window=window, mode=mode, cache=kv,
-        cache_pos=cache_pos, positions=positions, ring=ring,
+        cache_pos=cache_pos, layer=layer, positions=positions, ring=ring,
         seq_axis=seq_axis, tp=tp)
     if "moe" in lp:
         x, aux = MOE.moe_block_apply(lp["moe"], x, cfg)
@@ -308,7 +309,9 @@ def run_group_decode(gp, x, cfg, kind, cache, *, pos, windowed=False,
     Attention bodies read the cache and emit (k_new, v_new) deltas; the cache
     is written back with ONE stacked dynamic-update-slice per group after the
     layer scan (append-outside-scan, §Perf — a per-layer in-scan update
-    rewrites the full per-layer cache every layer)."""
+    rewrites the full per-layer cache every layer).  The Pallas decode
+    kernel reads each layer out of the stacked cache itself, so for it the
+    scan runs over the layer indices and nothing slices the cache."""
     window = cfg.sliding_window
     positions = pos[None] if pos.ndim == 0 else pos
 
@@ -324,15 +327,22 @@ def run_group_decode(gp, x, cfg, kind, cache, *, pos, windowed=False,
 
     if kind in (ATTN, SWA):
         ring = windowed and kind == SWA
+        in_place = cfg.kernel_impl == "pallas" and not ring
         def body(carry, inp):
-            lp, k_l, v_l = inp
+            if in_place:
+                (lp, layer), kv = inp, cache
+            else:
+                (lp, k_l, v_l), layer = inp, None
+                kv = {"k": k_l, "v": v_l}
             y, kv, _ = dense_layer_apply(lp, carry, cfg, window=_window(cfg, kind),
-                                         mode="decode", kv={"k": k_l, "v": v_l},
-                                         cache_pos=pos, positions=positions,
-                                         ring=ring, tp=tp)
+                                         mode="decode", kv=kv, cache_pos=pos,
+                                         positions=positions, ring=ring, tp=tp,
+                                         layer=layer)
             return y, (kv["k"], kv["v"])
+        xs = ((gp, jnp.arange(cache["k"].shape[0], dtype=jnp.int32))
+              if in_place else (gp, cache["k"], cache["v"]))
         with jax.named_scope("layers"):
-            x, (dk, dv) = lax.scan(body, x, (gp, cache["k"], cache["v"]))
+            x, (dk, dv) = lax.scan(body, x, xs)
         return x, {"k": put(cache["k"], dk, ring), "v": put(cache["v"], dv, ring)}
 
     if kind == "local_global":

@@ -157,6 +157,41 @@ def test_decode_kernel_over_shards_equals_whole_cache(pos):
     assert bool(jnp.isfinite(got).all())
 
 
+@pytest.mark.parametrize("pos", [5, 32, 95, 127])
+def test_decode_kernel_over_shards_counts_the_new_token_once(pos):
+    """With the new token's K/V apart from a stacked cache split by
+    positions, only the part that holds its slot counts it, and the
+    combined output equals the kernel over the whole cache.  The cache's
+    own slot ``pos`` is stale (written after the step) and never read."""
+    from repro.kernels.decode_attention.ops import (decode_attention_kvmajor,
+                                                    decode_attention_stacked)
+    Bq, H, S, hd, n, L = 4, 8, 128, 16, 4, 2
+    ks = jax.random.split(jax.random.PRNGKey(pos), 5)
+    q = jax.random.normal(ks[0], (Bq, H, hd), jnp.float32)
+    k = jax.random.normal(ks[1], (L, Bq, 1, S, hd), jnp.float32)
+    v = jax.random.normal(ks[2], (L, Bq, 1, S, hd), jnp.float32)
+    new = {"k_new": jax.random.normal(ks[3], (Bq, 1, 1, hd), jnp.float32),
+           "v_new": jax.random.normal(ks[4], (Bq, 1, 1, hd), jnp.float32)}
+    k, v = k.at[:, :, :, pos].set(50.0), v.at[:, :, :, pos].set(50.0)
+    whole = decode_attention_stacked(q, k, v, pos, 1, **new)
+    part = S // n
+    outs, ms, ls = zip(*(decode_attention_stacked(
+        q, k[..., i * part:(i + 1) * part, :],
+        v[..., i * part:(i + 1) * part, :], pos - i * part, 1, stats=True,
+        **new) for i in range(n)))
+    w = shard_weights(jnp.stack(ms), jnp.stack(ls))
+    got = jnp.einsum("nbh,nbhd->bhd", w, jnp.stack(outs))
+    np.testing.assert_allclose(got, whole, atol=1e-5, rtol=1e-5)
+    kl = k[1].at[:, :, pos].set(new["k_new"][:, :, 0])
+    vl = v[1].at[:, :, pos].set(new["v_new"][:, :, 0])
+    np.testing.assert_allclose(whole, decode_attention_kvmajor(q, kl, vl, pos),
+                               atol=1e-5, rtol=1e-5)
+    for i in range(n):
+        if i * part > pos:                      # no live position here
+            assert bool(jnp.all(ms[i] == -jnp.inf))
+            assert bool(jnp.all(ls[i] == 0)) and bool(jnp.all(outs[i] == 0))
+
+
 def test_param_count():
     """20.06 B at the published widths (52 x 379.1 M in the layers, 302 M
     in wte, 50 M in wpe); at the tiny size the count is the leaves'."""

@@ -121,7 +121,10 @@ def test_moe_aux_loss_and_capacity():
                                   "mamba2_1p3b", "gemma2_2b", "granite_20b"])
 def test_pallas_kernel_path_matches_xla(arch, rng):
     """kernel_impl='pallas' (interpret mode on CPU) must reproduce the XLA
-    path end-to-end: prefill logits and one decode step."""
+    path end-to-end: prefill logits, then three decode steps, each path
+    through its own cache (the Pallas decode kernel reads each layer of
+    the stacked cache in place), fed the XLA path's tokens: the same
+    logits within bf16 rounding and the same greedy tokens."""
     cfg_x = get_config(arch, tiny=True)
     cfg_p = cfg_x.replace(kernel_impl="pallas")
     params = api.init_params(rng, cfg_x)
@@ -133,9 +136,13 @@ def test_pallas_kernel_path_matches_xla(arch, rng):
                                np.asarray(lx, np.float32), atol=3e-2, rtol=3e-2)
 
     tok = jnp.argmax(lx, -1).astype(jnp.int32)
-    pos = jnp.asarray(64, jnp.int32)
-    dx, _ = api.decode_step(params, cx, tok, pos, cfg_x)
-    dp, _ = api.decode_step(params, cp, tok, pos, cfg_p)
-    np.testing.assert_allclose(np.asarray(dp, np.float32),
-                               np.asarray(dx, np.float32), atol=5e-2, rtol=5e-2)
-    assert int(jnp.argmax(dp)) == int(jnp.argmax(dx))
+    for i in range(3):
+        pos = jnp.asarray(64 + i, jnp.int32)
+        dx, cx = api.decode_step(params, cx, tok, pos, cfg_x)
+        dp, cp = api.decode_step(params, cp, tok, pos, cfg_p)
+        np.testing.assert_allclose(np.asarray(dp, np.float32),
+                                   np.asarray(dx, np.float32), atol=5e-2,
+                                   rtol=5e-2)
+        tok = jnp.argmax(dx, -1).astype(jnp.int32)
+        np.testing.assert_array_equal(np.asarray(jnp.argmax(dp, -1)),
+                                      np.asarray(tok))

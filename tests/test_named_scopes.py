@@ -101,9 +101,16 @@ def test_scopes_change_only_metadata(dense, kind, monkeypatch):
 
 
 def test_pallas_fold_in_is_a_cache_update_inside_attention(dense):
+    """The Pallas decode kernel folds the new token in itself: the kernel
+    lies under ``attention``, no cache write does, and the one write of
+    the new token is the stacked append after the scan."""
     cfg = dense.replace(kernel_impl="pallas")
     names = _op_names(_step(cfg, "decode").compile().as_text())
-    assert any("/attention/cache_update/" in n for n in names)
+    assert any(re.search(r"/layers/while/body/.*attention/.*decode_attention",
+                         n) for n in names)
+    assert not any("/attention/cache_update/" in n for n in names)
+    writes = [n for n in names if "/cache_update/" in n]
+    assert writes and not any("/layers/" in n for n in writes)
 
 
 def test_mamba_steps_carry_ssm():

@@ -7,7 +7,9 @@ import numpy as np
 import pytest
 
 from repro.kernels.decode_attention.decode_attention import _live_tiles
-from repro.kernels.decode_attention.ops import (decode_attention_kvmajor,
+from repro.kernels.decode_attention.ops import (cache_is_s_minor,
+                                                decode_attention_kvmajor,
+                                                decode_attention_stacked,
                                                 decode_tiling,
                                                 paged_decode_attention,
                                                 resolve_page_size)
@@ -202,3 +204,66 @@ def test_decode_kv_index_map_stops_at_live_tiles(pos, window, tiles):
     first, last = _live_tiles(jnp.asarray(pos), block_k=128, ns=4,
                               window=window)
     assert (int(first), int(last)) == tiles
+
+
+@pytest.mark.parametrize("pos,window,tiles", [
+    (128, None, (0, 0)), (129, None, (0, 1)), (0, None, (0, 0)),
+    (400, 144, (2, 3)),
+])
+def test_decode_kv_index_map_ends_below_pos_with_the_new_token_apart(
+        pos, window, tiles):
+    """With the new token's K/V apart, the cache's live positions end at
+    ``pos - 1``: a ``pos`` on a tile's first slot fetches no tile past the
+    one before it."""
+    first, last = _live_tiles(jnp.asarray(pos), block_k=128, ns=4,
+                              window=window, new=True)
+    assert (int(first), int(last)) == tiles
+
+
+# --- the kernel over a layer group's stacked cache, the new token apart ---
+
+STACKED_CASES = [
+    # (hd, H, KV, S, pos, window, layer, tiling): hd 64 is read as the
+    # S-minor view, hd 128 as the hd-minor one; G 3 (smollm) and G 48
+    # (granite's MQA); block_k 128 makes pos 200 fall inside a tile, 255 on
+    # a tile's last slot and 256 on the next tile's first
+    (64, 15, 5, 512, 200, None, 0, {"rows": 2, "block_k": 128}),
+    (64, 15, 5, 512, 255, None, 2, {"rows": 2, "block_k": 128}),
+    (64, 15, 5, 512, 256, None, 1, {"rows": 5, "block_k": 128}),
+    (64, 15, 5, 512, 511, None, 2, None),     # the cache's last slot
+    (128, 48, 1, 384, 200, None, 0, {"rows": 1, "block_k": 128}),
+    (128, 48, 1, 384, 255, None, 2, {"rows": 2, "block_k": 128}),
+    (128, 48, 1, 384, 0, None, 1, None),      # the first token
+    (64, 15, 5, 512, 300, 100, 2, {"rows": 2, "block_k": 128}),  # window
+    (128, 48, 1, 384, 300, 200, 0, {"rows": 1, "block_k": 128}),
+]
+
+
+@pytest.mark.parametrize("case", STACKED_CASES, ids=str)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_decode_attention_stacked_matches_ref(case, dtype):
+    """The kernel reads layer ``layer`` of a stacked (L, B, KV, S, hd) cache
+    and the new token's K/V apart; the cache's slot ``pos`` holds a stale
+    value that must not be read.  The plain reference sees that layer with
+    the new token in its slot."""
+    hd, H, KV, S, pos, window, layer, tiling = case
+    B, L = 2, 3
+    ks = jax.random.split(jax.random.PRNGKey(pos + layer), 5)
+    q = _rand(ks[0], (B, H, hd), dtype)
+    k = _rand(ks[1], (L, B, KV, S, hd), dtype)
+    v = _rand(ks[2], (L, B, KV, S, hd), dtype)
+    k_new = _rand(ks[3], (B, KV, 1, hd), dtype)
+    v_new = _rand(ks[4], (B, KV, 1, hd), dtype)
+    k = k.at[:, :, :, pos].set(100.0)           # stale: written after the step
+    v = v.at[:, :, :, pos].set(100.0)
+    p = jnp.asarray(pos, jnp.int32)
+    out = decode_attention_stacked(q, k, v, p, jnp.asarray(layer, jnp.int32),
+                                   k_new=k_new, v_new=v_new, window=window,
+                                   **(tiling or {}))
+    kl = k[layer].at[:, :, pos].set(k_new[:, :, 0]).transpose(0, 2, 1, 3)
+    vl = v[layer].at[:, :, pos].set(v_new[:, :, 0]).transpose(0, 2, 1, 3)
+    ref = decode_attention_ref(q, kl, vl, p, window=window)
+    assert cache_is_s_minor(S, hd, dtype) == (hd == 64)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), atol=tol, rtol=tol)

@@ -223,3 +223,74 @@ def test_granite_tp4_gathers_no_cache(granite_tp4, program):
                     r"= \w+\[([\d,]*)\]\{[^}]*\} all-gather(?:-start)?\(",
                     granite_tp4[program].as_text())]
     assert gathered and max(gathered) < one_layer, gathered
+
+
+# smollm-360m.decode-long's shapes: batch 64, 2048 positions
+DL_B, DL_C = 64, 2048
+
+
+@pytest.fixture(scope="module")
+def smollm_decode_long(topo):
+    """smollm-360m's decode program at decode-long's shapes on one chip,
+    made by `launch.steps.make_decode_step`, as the benchmark makes it."""
+    from jax.sharding import AxisType, Mesh
+    from repro.distributed.sharding import MeshInfo
+    from repro.launch import steps
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    with pytest.MonkeyPatch.context() as mp, mesh, _no_persistent_cache():
+        _on_chip(mp)
+        fn, args, _, _ = steps.make_decode_step(
+            SMOLLM.replace(kernel_impl="pallas"), MeshInfo(mesh),
+            InputShape("d", DL_C, DL_B, "decode"))
+        return fn.lower(*args).compile()
+
+
+def _instructions(text):
+    """{name: (dtype, dims, opcode)} of every instruction of an HLO text
+    that yields one array."""
+    return {name: (dt, tuple(int(d) for d in dims.split(",") if d), op)
+            for name, dt, dims, op in re.findall(
+                r"^\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\]\{[^}]*\} "
+                r"([\w-]+)\(", text, re.M)}
+
+
+@pytest.mark.parametrize("program,cfg,B,S", [
+    ("smollm_decode_long", SMOLLM, DL_B, DL_C),
+    ("granite_tp4", GRANITE, TP4_B, TP4_C // 4),
+])
+def test_decode_kernel_reads_the_stacked_cache(request, program, cfg, B, S):
+    """The decode kernel reads each layer's K/V out of the group's stacked
+    cache (S: the positions a chip holds): its K and V operands are the
+    whole stack, as it lies, and no op in the program slices, stages,
+    relays or folds the new token into one layer's K or V, or copies the
+    stack."""
+    compiled = request.getfixturevalue(program)
+    if program == "granite_tp4":
+        compiled = compiled["decode"]
+    text = compiled.as_text()
+    ops = _instructions(text)
+    layer = B * cfg.num_kv_heads * S * cfg.head_dim
+    stack = cfg.layer_groups[0][1] * layer
+
+    def size(dims):
+        return int(np.prod(dims)) if dims else 1
+
+    one_layer = [n for n, (dt, dims, op) in ops.items()
+                 if dt == "bf16" and size(dims) == layer
+                 and {S, cfg.head_dim} <= set(dims)]
+    assert not one_layer, one_layer
+    copies = [n for n, (dt, dims, op) in ops.items()
+              if op == "copy" and size(dims) == stack]
+    assert not copies, copies
+    calls = re.findall(r"%(\S*decode_attention\S*) = .*? custom-call\(([^)]*)\)"
+                       r", custom_call_target=\"tpu_custom_call\"", text)
+    assert calls
+    for name, operands in calls:
+        names = [o.strip().lstrip("%")
+                 for o in re.sub(r"/\*[^*]*\*/", "", operands).split(",")]
+        for kv in names[3:5]:            # after pos, the layer index and q
+            dt, dims, op = ops[kv]
+            assert size(dims) == stack and op in ("bitcast",
+                                                  "get-tuple-element"), (
+                name, kv, dims, op)
