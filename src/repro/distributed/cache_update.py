@@ -1,10 +1,17 @@
-"""Sharded KV-cache append (§Perf, append-outside-scan decode).
+"""The one writer of the K/V cache (``write``).
+
+A model step computes and never writes: prefill and decode return, for each
+layer group, the K/V they produced (stacked over the group's layers) and
+their recurrent states whole, in the cache's own tree.  ``write`` puts them
+into the cache at position ``pos``: states are replaced whole and K/V leaves
+are written along the sequence axis, by the mechanism the cache's layout
+calls for.
 
 A dynamic-update-slice at a traced position into a *model-sharded* sequence
 axis makes GSPMD all-gather the whole cache (measured: +790 ms collective on
-qwen2 decode_32k).  This helper performs the append under ``shard_map``: each
-device checks whether the global slot lands in its local shard and writes the
-one-token slice locally — O(token) traffic, zero collectives.
+qwen2 decode_32k).  Where the cache's positions are split over the mesh, each
+device instead writes, under ``shard_map``, the rows that fall in its own
+part: O(token) traffic, zero collectives.
 """
 
 from __future__ import annotations
@@ -33,8 +40,11 @@ def _shard(seq_axes, mesh_axis_sizes):
     return shard_idx, total
 
 
-def append_local(c_loc, d_loc, pos, *, seq_axes, mesh_axis_sizes, axis=3):
-    """Per-device body: write d (…,1,…) into c at global slot pos (mod cap)."""
+def _append_local(c_loc, d_loc, pos, *, seq_axes, mesh_axis_sizes, axis):
+    """Per-device body: write d (…,1,…) into c at global slot pos (mod cap).
+    The read of the old slot and the select keep a device's part unchanged
+    where the slot lies on another device; on one chip (a size-1 axis) the
+    slot always lies here, and the select is ROADMAP A2's target."""
     s_loc = c_loc.shape[axis]
     shard_idx, total = _shard(seq_axes, mesh_axis_sizes)
     cap = s_loc * total
@@ -45,26 +55,6 @@ def append_local(c_loc, d_loc, pos, *, seq_axes, mesh_axis_sizes, axis=3):
     cur = lax.dynamic_slice_in_dim(c_loc, local, 1, axis=axis)
     newv = jnp.where(in_range, d_loc.astype(c_loc.dtype), cur)
     return lax.dynamic_update_slice_in_dim(c_loc, newv, local, axis=axis)
-
-
-def append_kv(cache_leaf, delta_leaf, pos, spec: P, minfo, axis: int = 3):
-    """cache (count,B,KV,S,hd) with PartitionSpec `spec`; delta (…,1,…)."""
-    seq_axes = _axes_tuple(spec[axis]) if axis < len(spec) else ()
-    if not seq_axes:
-        cap = cache_leaf.shape[axis]
-        return lax.dynamic_update_slice_in_dim(
-            cache_leaf, delta_leaf.astype(cache_leaf.dtype), pos % cap,
-            axis=axis)
-
-    delta_spec = list(spec)
-    delta_spec[axis] = None
-    fn = functools.partial(append_local, seq_axes=seq_axes,
-                           mesh_axis_sizes=minfo.axis_sizes, axis=axis)
-    return jax.shard_map(
-        fn, mesh=minfo.mesh,
-        in_specs=(spec, P(*delta_spec), P()),
-        out_specs=spec,
-    )(cache_leaf, delta_leaf, pos)
 
 
 def _write_local(c_loc, u, pos, *, seq_axes, mesh_axis_sizes, axis):
@@ -79,27 +69,39 @@ def _write_local(c_loc, u, pos, *, seq_axes, mesh_axis_sizes, axis):
     return jnp.where(valid, piece.astype(c_loc.dtype), c_loc)
 
 
-def write_kv(cache_leaf, block, pos, spec: P, minfo, axis: int = 3):
-    """Write ``block`` (…,T,…) at positions [pos, pos + T) of the cache
-    whose sequence axis ``axis`` is split as ``spec`` says: each device
-    writes the rows that fall in its own part, from a block every device
-    holds whole (a prefill's K/V), so no device gathers the cache."""
-    seq_axes = _axes_tuple(spec[axis])
-    block_spec = list(spec)
-    block_spec[axis] = None
-    fn = functools.partial(_write_local, seq_axes=seq_axes,
+def _write_leaf(c, d, pos, spec, minfo):
+    """One cache leaf: a state (same shape) is replaced whole; K/V
+    (…, S, hd) get ``d``'s positions written along S from ``pos``."""
+    if c.shape == d.shape:
+        return d.astype(c.dtype)
+    axis = c.ndim - 2
+    seq_axes = _axes_tuple(spec[axis]) if spec is not None else ()
+    if not seq_axes:
+        return lax.dynamic_update_slice_in_dim(c, d.astype(c.dtype), pos,
+                                               axis=axis)
+    # positions over mesh axes: each device writes those in its own part
+    # (one token: decode; a block: prefill), from K/V every device holds
+    # whole, so no device gathers the cache
+    body = _append_local if d.shape[axis] == 1 else _write_local
+    d_spec = list(spec)
+    d_spec[axis] = None
+    fn = functools.partial(body, seq_axes=seq_axes,
                            mesh_axis_sizes=minfo.axis_sizes, axis=axis)
     return jax.shard_map(fn, mesh=minfo.mesh,
-                         in_specs=(spec, P(*block_spec), P()),
-                         out_specs=spec)(cache_leaf, block, pos)
+                         in_specs=(spec, P(*d_spec), P()),
+                         out_specs=spec)(c, d, jnp.asarray(pos, jnp.int32))
 
 
-def apply_cache_deltas(cache, deltas, pos, cache_specs, minfo):
-    """Walk the cache pytree: K/V leaves (S axis = -2) get the sharded append;
-    state leaves (matching shapes) are replaced wholesale."""
-    def go(c, d, spec):
-        if c.shape == d.shape:
-            return d.astype(c.dtype)
-        return append_kv(c, d, pos, spec, minfo, axis=c.ndim - 2)
-
-    return jax.tree.map(go, cache, deltas, cache_specs)
+def write(cache, new, pos, specs=None, minfo=None):
+    """Write a model step's output ``new`` (the cache's tree: K/V blocks
+    (count, B, KV, T, hd), states whole) into ``cache`` at position
+    ``pos``.  ``specs`` (the cache's PartitionSpecs) and ``minfo`` (its
+    mesh) give the layout; without them the cache is written as one array
+    (on one device, or where GSPMD is to place the write).  The mechanism
+    follows from them alone: K/V whose positions lie over mesh axes are
+    written by each device into its own part under ``shard_map``; any
+    other is a plain dynamic-update-slice."""
+    trees = (cache, new) if specs is None else (cache, new, specs)
+    with jax.named_scope("cache_update"):
+        return jax.tree.map(
+            lambda c, d, s=None: _write_leaf(c, d, pos, s, minfo), *trees)

@@ -34,8 +34,8 @@ from repro.perf import roofline
 
 def skip_reason(cfg, shape) -> str | None:
     if shape.name == "long_500k" and not cfg.supports_long_context:
-        return ("full-attention arch: long_500k requires sub-quadratic/"
-                "windowed attention (see DESIGN.md)")
+        return ("full-attention arch: long_500k needs an SSM, hybrid or "
+                "sliding-window arch (ModelConfig.supports_long_context)")
     return None
 
 
@@ -115,9 +115,7 @@ def main() -> None:
     ap.add_argument("--mesh", default="both", choices=["single", "multi", "both"])
     ap.add_argument("--out", default="experiments/dryrun")
     ap.add_argument("--variant", default="baseline",
-                    help="label; combine with --windowed/--param-mode/--micro")
-    ap.add_argument("--windowed", action="store_true",
-                    help="ring-buffer caches for sliding-window layers (decode)")
+                    help="label; combine with --param-mode/--micro")
     ap.add_argument("--param-mode", default=None,
                     help="override inference param sharding: infer|tp")
     ap.add_argument("--micro", type=int, default=None,
@@ -125,8 +123,6 @@ def main() -> None:
     args = ap.parse_args()
 
     step_kwargs = {}
-    if args.windowed:
-        step_kwargs["windowed_cache"] = True
     if args.param_mode:
         step_kwargs["param_mode"] = args.param_mode
     if args.micro:

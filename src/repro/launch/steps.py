@@ -8,7 +8,6 @@ Every builder returns ``(fn, arg_specs, in_shardings, out_shardings)`` where
 
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
@@ -33,8 +32,7 @@ def kernel_tp(cfg: ModelConfig, minfo: shd.MeshInfo, bspec,
               cache_specs=None) -> Optional[TensorParallel]:
     """The tensor-parallel layout under which the Pallas attention kernels
     run on each shard (``layers.cp_decode_attention``,
-    ``layers.tp_flash_attention``), with the prefill's cache write of that
-    layout (``distributed.cache_update``), or None where they run whole: one
+    ``layers.tp_flash_attention``), or None where they run whole: one
     shard, XLA attention, heads that do not split over the model axis, or
     a K/V cache whose positions are not split over exactly that axis."""
     axis = "model"
@@ -45,11 +43,7 @@ def kernel_tp(cfg: ModelConfig, minfo: shd.MeshInfo, bspec,
                              is_leaf=lambda x: isinstance(x, P)):
         if s[-2] not in (axis, (axis,)):
             return None
-    # the prefill's stacked K/V: (count, B, KV, T, hd)
-    write = functools.partial(cache_update.write_kv,
-                              spec=P(None, bspec, None, axis, None),
-                              minfo=minfo)
-    return TensorParallel(minfo.mesh, axis, write, bspec)
+    return TensorParallel(minfo.mesh, axis, bspec)
 
 
 def default_microbatches(cfg: ModelConfig, shape: InputShape,
@@ -134,17 +128,16 @@ def make_train_step(cfg: ModelConfig, minfo: shd.MeshInfo, shape: InputShape,
 def make_prefill_step(cfg: ModelConfig, minfo: shd.MeshInfo,
                       shape: InputShape, *, capacity: Optional[int] = None):
     capacity = capacity or shape.seq_len
+    B = shape.global_batch
     abstract_params = api.param_specs(cfg)
     p_specs = shd.param_specs(abstract_params, cfg, minfo, "infer")
     batch_abs = api.batch_specs(cfg, shape)
     b_specs = shd.batch_input_specs(batch_abs, minfo)
-    cache_abs = jax.eval_shape(
-        lambda: api.init_cache(cfg, shape.global_batch, capacity))
-    c_specs = shd.cache_specs_tree(cache_abs, cfg, minfo, shape.global_batch,
-                                   capacity)
-    logits_spec = P(shd.batch_spec_axes(minfo, shape.global_batch), None)
+    cache_abs = jax.eval_shape(lambda: api.init_cache(cfg, B, capacity))
+    c_specs = shd.cache_specs_tree(cache_abs, cfg, minfo, B, capacity)
+    logits_spec = P(shd.batch_spec_axes(minfo, B), None)
 
-    bspec = shd.batch_spec_axes(minfo, shape.global_batch)
+    bspec = shd.batch_spec_axes(minfo, B)
     # sequence-parallel attention (§Perf): when neither KV-head TP nor q-TP
     # applies, shard the prefill q-block axis over 'model' instead of
     # replicating the attention compute.
@@ -156,10 +149,15 @@ def make_prefill_step(cfg: ModelConfig, minfo: shd.MeshInfo,
         seq_axis = "model"
 
     tp = kernel_tp(cfg, minfo, bspec, c_specs)
+    # under the kernels' layout each chip writes its own positions of the
+    # prompt's K/V; otherwise GSPMD places the one write
+    layout = (c_specs, minfo) if tp else ()
 
     def prefill_step(params, batch):
-        return api.prefill(params, batch, cfg, capacity, bspec=bspec,
-                           seq_axis=seq_axis, tp=tp)
+        cache = api.init_cache(cfg, B, capacity)
+        logits, new = api.prefill_kv(params, batch, cfg, cache, bspec=bspec,
+                                     seq_axis=seq_axis, tp=tp)
+        return logits, cache_update.write(cache, new, 0, *layout)
 
     fn = jax.jit(prefill_step,
                  in_shardings=(_named(minfo, p_specs), _named(minfo, b_specs)),
@@ -172,16 +170,13 @@ def make_prefill_step(cfg: ModelConfig, minfo: shd.MeshInfo,
 # Decode step (serve_step for decode shapes)
 # ---------------------------------------------------------------------------
 def make_decode_step(cfg: ModelConfig, minfo: shd.MeshInfo,
-                     shape: InputShape, *, windowed_cache: bool = False,
-                     param_mode: str = "infer", sharded_append: bool = True):
-    """windowed_cache / param_mode='tp' are the beyond-paper §Perf variants:
-    ring-buffer caches for sliding-window layers, and TP-only inference params
-    (no per-layer FSDP all-gathers at decode)."""
+                     shape: InputShape, *, param_mode: str = "infer"):
+    """param_mode='tp' is the beyond-paper §Perf variant: TP-only inference
+    params (no per-layer FSDP all-gathers at decode)."""
     B, S = shape.global_batch, shape.seq_len
     abstract_params = api.param_specs(cfg)
     p_specs = shd.param_specs(abstract_params, cfg, minfo, param_mode)
-    cache_abs = jax.eval_shape(
-        lambda: api.init_cache(cfg, B, S, windowed=windowed_cache))
+    cache_abs = jax.eval_shape(lambda: api.init_cache(cfg, B, S))
     c_specs = shd.cache_specs_tree(cache_abs, cfg, minfo, B, S)
     tok_abs = jax.ShapeDtypeStruct((B,), jnp.int32)
     pos_abs = jax.ShapeDtypeStruct((), jnp.int32)
@@ -192,18 +187,11 @@ def make_decode_step(cfg: ModelConfig, minfo: shd.MeshInfo,
     tp = kernel_tp(cfg, minfo, bspec, c_specs)
 
     def decode(params, cache, tokens, pos):
-        if not sharded_append:
-            return api.decode_step(params, cache, tokens, pos, cfg, bspec=bspec,
-                                   windowed=windowed_cache, tp=tp)
-        # append-outside-scan + shard_map local write (§Perf): the cache is
-        # read-only inside the layer scan; one O(token) write per group.
-        from repro.distributed.cache_update import apply_cache_deltas
-        logits, deltas = api.decode_step(params, cache, tokens, pos, cfg,
-                                         bspec=bspec, windowed=windowed_cache,
-                                         return_deltas=True, tp=tp)
-        with jax.named_scope("cache_update"):
-            new_cache = apply_cache_deltas(cache, deltas, pos, c_specs, minfo)
-        return logits, new_cache
+        # append-outside-scan (§Perf): the cache is read-only inside the
+        # layer scan; one O(token) write per group after it.
+        logits, new = api.decode_kv(params, cache, tokens, pos, cfg,
+                                    bspec=bspec, tp=tp)
+        return logits, cache_update.write(cache, new, pos, c_specs, minfo)
 
     fn = jax.jit(
         decode,

@@ -9,6 +9,12 @@ All entry points are pure functions usable under ``jax.jit``,
   decode_step(params, cache, tokens, pos, cfg) -> (logits, cache)
   init_cache(cfg, batch, capacity)           -> cache pytree
   make_batch / batch_specs                   -> concrete / abstract inputs
+
+The models compute and never write the cache: ``prefill_kv`` and
+``decode_kv`` return the K/V (and states) a step produced, and
+``distributed.cache_update.write`` puts them into the cache.  ``prefill``
+and ``decode_step`` do both, for a cache on one device; the sharded step
+builders (``launch.steps``) give the writer the cache's layout.
 """
 
 from __future__ import annotations
@@ -20,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import InputShape, ModelConfig
+from repro.distributed import cache_update
 from repro.models import encdec, transformer
 
 Array = jax.Array
@@ -42,29 +49,41 @@ def train_loss(params, batch, cfg: ModelConfig, *, remat: bool = True,
     return transformer.train_loss(params, batch, cfg, remat=remat, bspec=bspec)
 
 
-def prefill(params, batch, cfg: ModelConfig, capacity: int, bspec=None,
-            seq_axis=None, tp=None):
+def prefill_kv(params, batch, cfg: ModelConfig, cache, bspec=None,
+               seq_axis=None, tp=None):
+    """(last_logits, new): the prompt's K/V and states, in ``cache``'s tree,
+    for ``cache_update.write`` at position 0."""
     if _is_encdec(cfg):
-        return encdec.prefill(params, batch, cfg, capacity, bspec=bspec)
-    return transformer.prefill(params, batch, cfg, capacity, bspec=bspec,
+        return encdec.prefill(params, batch, cfg, bspec=bspec)
+    return transformer.prefill(params, batch, cfg, cache, bspec=bspec,
                                seq_axis=seq_axis, tp=tp)
 
 
-def decode_step(params, cache, tokens, pos, cfg: ModelConfig, bspec=None,
-                windowed: bool = False, return_deltas: bool = False, tp=None):
+def decode_kv(params, cache, tokens, pos, cfg: ModelConfig, bspec=None,
+              tp=None):
+    """(logits, new): the tokens' K/V and the new states, in ``cache``'s
+    tree, for ``cache_update.write`` at ``pos``."""
     if _is_encdec(cfg):
-        return encdec.decode_step(params, cache, tokens, pos, cfg, bspec=bspec,
-                                  return_deltas=return_deltas)
-    return transformer.decode_step(params, cache, tokens, pos, cfg, bspec=bspec,
-                                   windowed=windowed,
-                                   return_deltas=return_deltas, tp=tp)
+        return encdec.decode_step(params, cache, tokens, pos, cfg, bspec=bspec)
+    return transformer.decode_step(params, cache, tokens, pos, cfg,
+                                   bspec=bspec, tp=tp)
 
 
-def init_cache(cfg: ModelConfig, batch: int, capacity: int,
-               windowed: bool = False):
+def prefill(params, batch, cfg: ModelConfig, capacity: int):
+    cache = init_cache(cfg, batch["tokens"].shape[0], capacity)
+    logits, new = prefill_kv(params, batch, cfg, cache)
+    return logits, cache_update.write(cache, new, 0)
+
+
+def decode_step(params, cache, tokens, pos, cfg: ModelConfig):
+    logits, new = decode_kv(params, cache, tokens, pos, cfg)
+    return logits, cache_update.write(cache, new, pos)
+
+
+def init_cache(cfg: ModelConfig, batch: int, capacity: int):
     if _is_encdec(cfg):
         return encdec.init_cache(cfg, batch, capacity)
-    return transformer.init_cache(cfg, batch, capacity, windowed=windowed)
+    return transformer.init_cache(cfg, batch, capacity)
 
 
 # ---------------------------------------------------------------------------

@@ -4,7 +4,8 @@ The mel-spectrogram + conv feature extractor is stubbed per the brief:
 ``input_specs`` supplies precomputed frame embeddings (B, encoder_seq_len,
 d_model).  The encoder is bidirectional; the decoder has causal self-attention
 (RoPE, cached at decode) plus cross-attention over per-layer encoder K/V that
-are computed once at prefill and stored in the cache.
+are computed once at prefill and stored in the cache (by
+``distributed.cache_update``, from what prefill and decode return).
 """
 
 from __future__ import annotations
@@ -64,10 +65,9 @@ def encode(params, audio_embeds: Array, cfg, bspec=None) -> Array:
 
 
 def _decoder_trunk(params, x, cfg, enc_out, *, mode, cache=None, pos=None,
-                   positions=None, remat=False, bspec=None,
-                   return_deltas=False):
+                   positions=None, remat=False, bspec=None):
     """Runs decoder layers.  For prefill/decode the cache is
-    {'k','v': (L,B,cap,KV,hd), 'ck','cv': (L,B,S_enc,KV,hd)}."""
+    {'k','v': (L,B,KV,cap,hd), 'ck','cv': (L,B,S_enc,KV,hd)}."""
     if mode == "train":
         def body(carry, lp):
             carry = L.constrain_batch(carry, bspec)
@@ -105,13 +105,7 @@ def _decoder_trunk(params, x, cfg, enc_out, *, mode, cache=None, pos=None,
 
     x, (dk, dv) = lax.scan(body, x, (params["dec_layers"], cache["k"], cache["v"],
                                      cache["ck"], cache["cv"]))
-    if return_deltas:
-        return x, (dk, dv)
-    ks = lax.dynamic_update_slice_in_dim(cache["k"], dk.astype(cache["k"].dtype),
-                                         pos, axis=3)
-    vs = lax.dynamic_update_slice_in_dim(cache["v"], dv.astype(cache["v"].dtype),
-                                         pos, axis=3)
-    return x, (ks, vs)
+    return x, (dk, dv)
 
 
 def train_loss(params, batch, cfg, *, remat=True, bspec=None):
@@ -131,26 +125,21 @@ def train_loss(params, batch, cfg, *, remat=True, bspec=None):
     return ce, {"ce": ce, "aux": jnp.zeros((), jnp.float32)}
 
 
-def prefill(params, batch, cfg, capacity: int, bspec=None):
+def prefill(params, batch, cfg, bspec=None):
+    """Returns (last_logits, new): the cache's tree with the prompt's K/V
+    (L, B, KV, T, hd) and the cross K/V whole."""
     from repro.models.transformer import logits_last
     tokens = batch["tokens"]
     enc_out = encode(params, batch["audio_embeds"], cfg, bspec)
     x = L.constrain_batch(params["embed"][tokens].astype(jnp.dtype(cfg.dtype)),
                           bspec)
-    B, T = tokens.shape
-    positions = jnp.arange(T)
+    positions = jnp.arange(tokens.shape[1])
     h, (ks, vs, cks, cvs) = _decoder_trunk(params, x, cfg, enc_out,
                                            mode="prefill", positions=positions)
     h = L.rmsnorm(h, params["final_norm"], cfg.norm_eps)
-    Ld = cfg.num_layers
-    dt = jnp.dtype(cfg.dtype)
-    KV, hd = cfg.num_kv_heads, cfg.head_dim
-    k_buf = jnp.zeros((Ld, B, KV, capacity, hd), dt).at[:, :, :, :T].set(
-        ks.astype(dt).transpose(0, 1, 3, 2, 4))
-    v_buf = jnp.zeros((Ld, B, KV, capacity, hd), dt).at[:, :, :, :T].set(
-        vs.astype(dt).transpose(0, 1, 3, 2, 4))
-    cache = {"k": k_buf, "v": v_buf, "ck": cks.astype(dt), "cv": cvs.astype(dt)}
-    return logits_last(params, h[:, -1], cfg), cache
+    new = {"k": ks.transpose(0, 1, 3, 2, 4), "v": vs.transpose(0, 1, 3, 2, 4),
+           "ck": cks, "cv": cvs}
+    return logits_last(params, h[:, -1], cfg), new
 
 
 def init_cache(cfg, batch: int, capacity: int) -> dict:
@@ -165,14 +154,14 @@ def init_cache(cfg, batch: int, capacity: int) -> dict:
     }
 
 
-def decode_step(params, cache, tokens, pos, cfg, bspec=None,
-                return_deltas=False):
+def decode_step(params, cache, tokens, pos, cfg, bspec=None):
+    """Returns (logits, new): the cache's tree with the tokens' K/V
+    (L, B, KV, 1, hd) and the cross K/V unchanged."""
     from repro.models.transformer import logits_last
     x = L.constrain_batch(params["embed"][tokens[:, None]].astype(jnp.dtype(cfg.dtype)),
                           bspec)
-    h, (ks, vs) = _decoder_trunk(params, x, cfg, None, mode="decode",
-                                 cache=cache, pos=pos,
-                                 return_deltas=return_deltas)
+    h, (dk, dv) = _decoder_trunk(params, x, cfg, None, mode="decode",
+                                 cache=cache, pos=pos)
     h = L.rmsnorm(h, params["final_norm"], cfg.norm_eps)
-    new_cache = {"k": ks, "v": vs, "ck": cache["ck"], "cv": cache["cv"]}
-    return logits_last(params, h[:, 0], cfg), new_cache
+    new = {"k": dk, "v": dv, "ck": cache["ck"], "cv": cache["cv"]}
+    return logits_last(params, h[:, 0], cfg), new

@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Callable, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -29,13 +29,9 @@ class TensorParallel:
     ``shard_map``, as the step builder hands it to the model: query heads
     (and the projections around them) are split over the mesh axis
     ``axis``, the decode cache's positions over the same axis, and the
-    batch over the mesh axes ``batch`` (None: replicated).  The prefill's
-    cache write comes with it: ``write(buf, kv, pos)`` writes a prefill's
-    K/V (count, B, KV, T, hd), whole on every shard, at positions [pos,
-    pos + T) of the split cache."""
+    batch over the mesh axes ``batch`` (None: replicated)."""
     mesh: Mesh
     axis: str
-    write: Callable
     batch: Optional[tuple] = None
 
     @property
@@ -375,10 +371,9 @@ def decode_attention(
     *,
     window: Optional[int] = None,
     logit_cap: Optional[float] = None,
-    k_new: Optional[Array] = None,   # (B, KV, 1, hd) — the new token's K/V,
-    v_new: Optional[Array] = None,   # attended separately (append-outside-scan
-    exclude_slot: Optional[Array] = None,  # ring buffers: stale slot to mask
-) -> Array:                          # decode, §Perf: cache stays read-only)
+    k_new: Array,    # (B, KV, 1, hd) — the new token's K/V, attended
+    v_new: Array,    # separately (append-outside-scan decode, §Perf: the
+) -> Array:          # cache stays read-only)
     B, H, hd = q.shape
     _, KV, S, _ = k_cache.shape
     G = H // KV
@@ -388,18 +383,10 @@ def decode_attention(
                    preferred_element_type=jnp.float32)
     s = softcap(s, logit_cap)
     kpos = jnp.arange(S)
-    # with k_new provided, the cache holds positions < pos (slot pos stale)
-    mask = (kpos < pos) if k_new is not None else (kpos <= pos)
+    mask = kpos < pos            # the cache's slot pos is not yet written
     if window is not None:
         mask = mask & (kpos > pos - window)
-    if exclude_slot is not None:
-        mask = mask & (kpos != exclude_slot)
     s = jnp.where(mask[None, None, None], s, NEG_INF)
-    if k_new is None:
-        p = jax.nn.softmax(s, axis=-1)
-        out = jnp.einsum("bkgs,bksd->bkgd", p.astype(v_cache.dtype), v_cache,
-                         preferred_element_type=jnp.float32)
-        return out.reshape(B, H, hd).astype(q.dtype)
 
     # two-part softmax: combine cache scores (sequence axis may be sharded —
     # a concat would make GSPMD gather the score matrix) with the new token's
@@ -538,20 +525,14 @@ def attn_block_apply(
     cache_pos: Optional[Array] = None,   # scalar int32
     layer: Optional[Array] = None,       # cache is the stack; read this layer
     mode: str = "train",                 # train | prefill | decode
-    ring: bool = False,                  # windowed ring-buffer cache (decode)
     seq_axis: Optional[str] = None,      # sequence-parallel attention (prefill)
     tp: Optional[TensorParallel] = None,  # Pallas kernels under shard_map
 ):
-    """Returns (y, new_kv) where new_kv is (k, v) for prefill, updated cache for
-    decode, and None for train.
+    """Returns (y, new_kv) where new_kv is {k, v}: the block's K/V
+    (B, T, KV, hd) for prefill, the new token's (B, KV, 1, hd) for decode
+    (the cache stays read-only), and None for train.
 
-    ring=True (sliding-window archs, §Perf): the cache holds only the last
-    ``window`` positions; the write slot is ``pos % capacity`` and attention
-    reads the whole (unmasked) ring — valid once pos >= capacity-1, which the
-    serving engine guarantees by prefilling ≥ window tokens.  Keys carry
-    absolute RoPE so ring order does not matter.
-
-    layer (Pallas decode, not ring): ``cache`` is the group's stacked cache
+    layer (Pallas decode): ``cache`` is the group's stacked cache
     (L, B, KV, S, hd) and the kernel reads this layer of it in place.
 
     tp (with kernel_impl 'pallas'): the kernels run on each shard of a
@@ -569,11 +550,10 @@ def attn_block_apply(
 
     if mode == "decode":
         assert cache is not None and T == 1
-        capacity = cache["k"].shape[-2]         # (B, KV, S, hd)
         k_new = k.transpose(0, 2, 1, 3).astype(cache["k"].dtype)  # (B,KV,1,hd)
         v_new = v.transpose(0, 2, 1, 3).astype(cache["v"].dtype)
         new_kv = {"k": k_new, "v": v_new}
-        if pallas and not ring:
+        if pallas:
             # the kernel reads the cache in place, the new token apart: the
             # cache stays read-only, as on the XLA path
             from repro.kernels.decode_attention.ops import (
@@ -593,18 +573,14 @@ def attn_block_apply(
                                              window=window,
                                              logit_cap=cfg.attn_logit_softcap)
         else:
-            # append-outside-scan: the cache is read-only here; the caller
-            # writes the returned (k_new, v_new) delta once per step (one
-            # stacked DUS outside the layer scan instead of a full cache
-            # rewrite per layer).
-            o = decode_attention(q[:, 0], cache["k"], cache["v"],
-                                 jnp.asarray(capacity, jnp.int32) if ring
-                                 else cache_pos,
-                                 window=None if ring else window,
+            # append-outside-scan: the cache is read-only here; the
+            # returned (k_new, v_new) is written once per step (one stacked
+            # write outside the layer scan instead of a full cache rewrite
+            # per layer).
+            o = decode_attention(q[:, 0], cache["k"], cache["v"], cache_pos,
+                                 window=window,
                                  logit_cap=cfg.attn_logit_softcap,
-                                 k_new=k_new, v_new=v_new,
-                                 exclude_slot=(cache_pos % capacity) if ring
-                                 else None)
+                                 k_new=k_new, v_new=v_new)
         o = o[:, None]                            # (B, 1, H, hd)
     elif mode == "prefill" and pallas and causal:
         # Pallas flash-attention kernel (interpret mode on CPU; TPU target)

@@ -5,8 +5,9 @@ parameters are stacked on a leading axis and executed with ``lax.scan`` so
 that 80-layer models lower to a compact HLO.  Three modes:
 
   train   — full-sequence forward, chunked cross-entropy loss
-  prefill — full-sequence forward, returns last-position logits + KV cache
-  decode  — one token against the cache (the serving hot path)
+  prefill — full-sequence forward, returns last-position logits + its K/V
+  decode  — one token against the cache (the serving hot path), returns
+            logits + its K/V; ``distributed.cache_update`` writes them
 """
 
 from __future__ import annotations
@@ -93,11 +94,10 @@ def init_params(rng, cfg) -> dict:
 # Layer application
 # ---------------------------------------------------------------------------
 def dense_layer_apply(lp, x, cfg, *, window, mode, kv=None, cache_pos=None,
-                      positions=None, ring=False, seq_axis=None, tp=None,
-                      layer=None):
+                      positions=None, seq_axis=None, tp=None, layer=None):
     x, new_kv = L.attn_block_apply(
         lp["attn"], x, cfg, window=window, mode=mode, cache=kv,
-        cache_pos=cache_pos, layer=layer, positions=positions, ring=ring,
+        cache_pos=cache_pos, layer=layer, positions=positions,
         seq_axis=seq_axis, tp=tp)
     if "moe" in lp:
         x, aux = MOE.moe_block_apply(lp["moe"], x, cfg)
@@ -125,38 +125,29 @@ def _stacked_mamba_state(cfg, shape_prefix: tuple, batch: int, dt) -> dict:
     }
 
 
-def init_cache(cfg, batch: int, capacity: int, windowed: bool = False) -> list:
-    """windowed=True (beyond-paper §Perf): sliding-window layers allocate
-    only ``window`` slots (ring buffer) instead of the full context."""
+def init_cache(cfg, batch: int, capacity: int) -> list:
+    """Every attention layer, sliding-window ones too, holds ``capacity``
+    positions (a window is a mask over them)."""
     dt = jnp.dtype(cfg.dtype)
     KV, hd = cfg.num_kv_heads, cfg.head_dim
-    wcap = capacity
-    if windowed and cfg.sliding_window:
-        wcap = min(capacity, cfg.sliding_window)
+
+    def kv(count):
+        return {"k": jnp.zeros((count, batch, KV, capacity, hd), dt),
+                "v": jnp.zeros((count, batch, KV, capacity, hd), dt)}
+
     caches = []
     for kind, count in cfg.layer_groups:
         if kind in (ATTN, SWA):
-            cap = wcap if kind == SWA else capacity
-            caches.append({
-                "k": jnp.zeros((count, batch, KV, cap, hd), dt),
-                "v": jnp.zeros((count, batch, KV, cap, hd), dt),
-            })
+            caches.append(kv(count))
         elif kind == "local_global":
-            caches.append({
-                "local": {"k": jnp.zeros((count, batch, KV, wcap, hd), dt),
-                          "v": jnp.zeros((count, batch, KV, wcap, hd), dt)},
-                "global": {"k": jnp.zeros((count, batch, KV, capacity, hd), dt),
-                           "v": jnp.zeros((count, batch, KV, capacity, hd), dt)},
-            })
+            caches.append({"local": kv(count), "global": kv(count)})
         elif kind == MAMBA:
             caches.append(_stacked_mamba_state(cfg, (count,), batch, dt))
         elif kind == "hybrid_super":
             inner = cfg.hybrid_attn_every
             caches.append({
                 "mamba": _stacked_mamba_state(cfg, (count, inner), batch, dt),
-                "k": jnp.zeros((count, batch, KV, wcap, hd), dt),
-                "v": jnp.zeros((count, batch, KV, wcap, hd), dt),
-            })
+                **kv(count)})
         else:
             raise ValueError(kind)
     return caches
@@ -222,20 +213,19 @@ def run_group_train(gp, x, cfg, kind, *, positions, remat=False, bspec=None):
     raise ValueError(kind)
 
 
-def run_group_prefill(gp, x, cfg, kind, cache, *, positions, cache_pos=0,
-                      seq_axis=None, tp=None):
-    """Forward with cache write-back at [cache_pos, cache_pos+T).
+def _kv_block(kv):
+    """A layer scan's K or V (count, B, T, KV, hd) in the cache's layout
+    (count, B, KV, T, hd)."""
+    return kv.transpose(0, 1, 3, 2, 4)
+
+
+def run_group_prefill(gp, x, cfg, kind, cache, *, positions, seq_axis=None,
+                      tp=None):
+    """Full-sequence forward from the group's cache (its states: the K/V
+    are not read).  Returns (x, new, aux): ``new`` is the cache's tree with
+    the K/V blocks (count, B, KV, T, hd) and the states at the end.
     tp: the Pallas kernels' tensor-parallel layout (``L.TensorParallel``)."""
     window = cfg.sliding_window
-    T = x.shape[1]
-
-    @L.named_scope("cache_update")
-    def put(buf, kv):  # buf (count,B,KV,cap,hd); kv (count,B,T,KV,hd)
-        kv = kv.transpose(0, 1, 3, 2, 4)         # -> (count,B,KV,T,hd)
-        if tp is not None:  # each shard writes its own positions
-            return tp.write(buf, kv, jnp.asarray(cache_pos, jnp.int32))
-        return lax.dynamic_update_slice_in_dim(buf, kv.astype(buf.dtype),
-                                               cache_pos, axis=3)
 
     if kind in (ATTN, SWA):
         def body(carry, lp):
@@ -245,8 +235,7 @@ def run_group_prefill(gp, x, cfg, kind, cache, *, positions, cache_pos=0,
             return y, (kv["k"], kv["v"], aux)
         with jax.named_scope("layers"):
             x, (ks, vs, auxs) = lax.scan(body, x, gp)
-        new_cache = {"k": put(cache["k"], ks), "v": put(cache["v"], vs)}
-        return x, new_cache, auxs.sum()
+        return x, {"k": _kv_block(ks), "v": _kv_block(vs)}, auxs.sum()
 
     if kind == "local_global":
         def body(carry, lp):
@@ -259,13 +248,9 @@ def run_group_prefill(gp, x, cfg, kind, cache, *, positions, cache_pos=0,
             return y, (kv_l["k"], kv_l["v"], kv_g["k"], kv_g["v"], a1 + a2)
         with jax.named_scope("layers"):
             x, (kl, vl, kg, vg, auxs) = lax.scan(body, x, gp)
-        new_cache = {
-            "local": {"k": put(cache["local"]["k"], kl),
-                      "v": put(cache["local"]["v"], vl)},
-            "global": {"k": put(cache["global"]["k"], kg),
-                       "v": put(cache["global"]["v"], vg)},
-        }
-        return x, new_cache, auxs.sum()
+        new = {"local": {"k": _kv_block(kl), "v": _kv_block(vl)},
+               "global": {"k": _kv_block(kg), "v": _kv_block(vg)}}
+        return x, new, auxs.sum()
 
     if kind == MAMBA:
         def body(carry, inp):
@@ -293,41 +278,28 @@ def run_group_prefill(gp, x, cfg, kind, cache, *, positions, cache_pos=0,
         with jax.named_scope("layers"):
             x, (new_m, ks, vs) = lax.scan(body, x, (gp["mamba"],
                                                     cache["mamba"]))
-        new_cache = {"mamba": new_m, "k": put(cache["k"], ks),
-                     "v": put(cache["v"], vs)}
-        return x, new_cache, jnp.zeros((), jnp.float32)
+        new = {"mamba": new_m, "k": _kv_block(ks), "v": _kv_block(vs)}
+        return x, new, jnp.zeros((), jnp.float32)
 
     raise ValueError(kind)
 
 
-def run_group_decode(gp, x, cfg, kind, cache, *, pos, windowed=False,
-                     return_deltas=False, tp=None):
+def run_group_decode(gp, x, cfg, kind, cache, *, pos, tp=None):
     """One-token step.  pos: scalar int32 — index where the new token lands.
-    windowed=True: sliding-window layers use ring-buffer caches.
     tp: the Pallas kernels' tensor-parallel layout (``L.TensorParallel``).
 
-    Attention bodies read the cache and emit (k_new, v_new) deltas; the cache
-    is written back with ONE stacked dynamic-update-slice per group after the
-    layer scan (append-outside-scan, §Perf — a per-layer in-scan update
-    rewrites the full per-layer cache every layer).  The Pallas decode
-    kernel reads each layer out of the stacked cache itself, so for it the
-    scan runs over the layer indices and nothing slices the cache."""
+    Returns (x, new): ``new`` is the cache's tree with the token's K/V
+    (count, B, KV, 1, hd) and the new states.  The cache stays read-only:
+    its one write per group comes after the step (append-outside-scan,
+    §Perf — a per-layer in-scan update rewrites the full per-layer cache
+    every layer).  The Pallas decode kernel reads each layer out of the
+    stacked cache itself, so for it the scan runs over the layer indices
+    and nothing slices the cache."""
     window = cfg.sliding_window
     positions = pos[None] if pos.ndim == 0 else pos
 
-    @L.named_scope("cache_update")
-    def put(buf, delta, ring):
-        # buf (count,B,KV,cap,hd); delta (count,B,KV,1,hd)
-        if return_deltas:
-            return delta        # caller applies a sharded append (§Perf)
-        cap = buf.shape[3]
-        slot = (pos % cap) if ring else pos
-        return lax.dynamic_update_slice_in_dim(buf, delta.astype(buf.dtype),
-                                               slot, axis=3)
-
     if kind in (ATTN, SWA):
-        ring = windowed and kind == SWA
-        in_place = cfg.kernel_impl == "pallas" and not ring
+        in_place = cfg.kernel_impl == "pallas"
         def body(carry, inp):
             if in_place:
                 (lp, layer), kv = inp, cache
@@ -336,22 +308,21 @@ def run_group_decode(gp, x, cfg, kind, cache, *, pos, windowed=False,
                 kv = {"k": k_l, "v": v_l}
             y, kv, _ = dense_layer_apply(lp, carry, cfg, window=_window(cfg, kind),
                                          mode="decode", kv=kv, cache_pos=pos,
-                                         positions=positions, ring=ring, tp=tp,
+                                         positions=positions, tp=tp,
                                          layer=layer)
             return y, (kv["k"], kv["v"])
         xs = ((gp, jnp.arange(cache["k"].shape[0], dtype=jnp.int32))
               if in_place else (gp, cache["k"], cache["v"]))
         with jax.named_scope("layers"):
             x, (dk, dv) = lax.scan(body, x, xs)
-        return x, {"k": put(cache["k"], dk, ring), "v": put(cache["v"], dv, ring)}
+        return x, {"k": dk, "v": dv}
 
     if kind == "local_global":
         def body(carry, inp):
             lp, kl, vl, kg, vg = inp
             y, kv_l, _ = dense_layer_apply(lp["local"], carry, cfg, window=window,
                                            mode="decode", kv={"k": kl, "v": vl},
-                                           cache_pos=pos, positions=positions,
-                                           ring=windowed)
+                                           cache_pos=pos, positions=positions)
             y, kv_g, _ = dense_layer_apply(lp["global"], y, cfg, window=None,
                                            mode="decode", kv={"k": kg, "v": vg},
                                            cache_pos=pos, positions=positions)
@@ -360,12 +331,8 @@ def run_group_decode(gp, x, cfg, kind, cache, *, pos, windowed=False,
             x, (dkl, dvl, dkg, dvg) = lax.scan(
                 body, x, (gp, cache["local"]["k"], cache["local"]["v"],
                           cache["global"]["k"], cache["global"]["v"]))
-        return x, {
-            "local": {"k": put(cache["local"]["k"], dkl, windowed),
-                      "v": put(cache["local"]["v"], dvl, windowed)},
-            "global": {"k": put(cache["global"]["k"], dkg, False),
-                       "v": put(cache["global"]["v"], dvg, False)},
-        }
+        return x, {"local": {"k": dkl, "v": dvl},
+                   "global": {"k": dkg, "v": dvg}}
 
     if kind == MAMBA:
         def body(carry, inp):
@@ -389,15 +356,13 @@ def run_group_decode(gp, x, cfg, kind, cache, *, pos, windowed=False,
             y, new_mstates = lax.scan(inner, y, (mp_stack, mstates))
             y, kv, _ = dense_layer_apply(shared, y, cfg, window=window,
                                          mode="decode", kv={"k": k_l, "v": v_l},
-                                         cache_pos=pos, positions=positions,
-                                         ring=windowed)
+                                         cache_pos=pos, positions=positions)
             return y, (new_mstates, kv["k"], kv["v"])
         with jax.named_scope("layers"):
             x, (new_m, dk, dv) = lax.scan(body, x, (gp["mamba"],
                                                     cache["mamba"],
                                                     cache["k"], cache["v"]))
-        return x, {"mamba": new_m, "k": put(cache["k"], dk, windowed),
-                   "v": put(cache["v"], dv, windowed)}
+        return x, {"mamba": new_m, "k": dk, "v": dv}
 
     raise ValueError(kind)
 
@@ -500,42 +465,39 @@ def train_loss(params, batch, cfg, *, remat=True, bspec=None):
     return loss, {"ce": ce, "aux": aux}
 
 
-def prefill(params, batch, cfg, capacity: int, bspec=None, seq_axis=None,
-            tp=None):
-    """Returns (last_logits (B,V) f32, cache) with cache capacity ``capacity``."""
+def prefill(params, batch, cfg, cache, bspec=None, seq_axis=None, tp=None):
+    """A prompt from position 0 against ``cache`` (its states; nothing is
+    written).  Returns (last_logits (B,V) f32, new): ``new`` is the cache's
+    tree with the prompt's K/V (count, B, KV, T, hd) and the states."""
     tokens = batch["tokens"]
     patches = batch.get("patch_embeds")
-    B = tokens.shape[0]
     T = tokens.shape[1] + (0 if patches is None else patches.shape[1])
     positions = jnp.arange(T)
     x = L.constrain_batch(embed_tokens(params, tokens, cfg, patch_embeds=patches,
                                        positions=positions), bspec)
-    cache = init_cache(cfg, B, capacity)
-    new_cache = []
+    new = []
     for gp, c, (kind, count) in zip(params["groups"], cache, cfg.layer_groups):
         x, nc, _ = run_group_prefill(gp, x, cfg, kind, c, positions=positions,
                                      seq_axis=seq_axis, tp=tp)
-        new_cache.append(nc)
+        new.append(nc)
     with jax.named_scope("logits"):
         x = L.norm(params, "final_norm", x, cfg)
         logits = logits_last(params, x[:, -1], cfg)
-    return logits, new_cache
+    return logits, new
 
 
-def decode_step(params, cache, tokens, pos, cfg, bspec=None, windowed=False,
-                return_deltas=False, tp=None):
+def decode_step(params, cache, tokens, pos, cfg, bspec=None, tp=None):
     """tokens: (B,) int32 new token ids; pos: scalar int32 slot index.
 
-    Returns (logits (B,V) f32, new_cache) — or, with return_deltas, the
-    per-group K/V deltas for a sharded append (distributed.cache_update)."""
+    Returns (logits (B,V) f32, new): ``new`` is the cache's tree with the
+    tokens' K/V (count, B, KV, 1, hd) and the new states."""
     x = L.constrain_batch(embed_tokens(params, tokens[:, None], cfg,
                                        positions=pos[None]), bspec)
-    new_cache = []
+    new = []
     for gp, c, (kind, count) in zip(params["groups"], cache, cfg.layer_groups):
-        x, nc = run_group_decode(gp, x, cfg, kind, c, pos=pos, windowed=windowed,
-                                 return_deltas=return_deltas, tp=tp)
-        new_cache.append(nc)
+        x, nc = run_group_decode(gp, x, cfg, kind, c, pos=pos, tp=tp)
+        new.append(nc)
     with jax.named_scope("logits"):
         x = L.norm(params, "final_norm", x, cfg)
         logits = logits_last(params, x[:, 0], cfg)
-    return logits, new_cache
+    return logits, new

@@ -168,7 +168,7 @@ shape = InputShape("d", S, B, "decode")
 rng = jax.random.PRNGKey(0)
 params = api.init_params(rng, cfg)
 
-# reference: single-logical-device decode via the internal put path
+# reference: single-device decode (api; a plain cache write)
 prefix = jax.random.randint(rng, (B, S - 1), 0, cfg.vocab_size, jnp.int32)
 _, cache = api.prefill(params, {"tokens": prefix}, cfg, capacity=S)
 tok = jax.random.randint(jax.random.PRNGKey(1), (B,), 0, cfg.vocab_size, jnp.int32)

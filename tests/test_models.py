@@ -56,21 +56,64 @@ def test_smoke_prefill_decode_shapes(arch, rng):
     assert bool(jnp.isfinite(logits2).all())
 
 
+def _api_path(cfg, capacity):
+    """Prefill and decode on one device (``models.api``)."""
+    def prefill(params, tokens):
+        return api.prefill(params, {"tokens": tokens}, cfg, capacity=capacity)
+
+    def decode(params, cache, tok, pos):
+        return api.decode_step(params, cache, tok, pos, cfg)
+    return prefill, decode
+
+
+def _steps_path(cfg, capacity):
+    """Prefill and decode by the chip's step builders (``launch.steps``),
+    as the benchmark builds them, on a 1x1 host mesh."""
+    from repro.launch import steps
+    from repro.launch.mesh import make_host_mesh
+    minfo = make_host_mesh(1, 1)
+
+    def prefill(params, tokens):
+        B, T = tokens.shape
+        with minfo.mesh:
+            fn = steps.make_prefill_step(
+                cfg, minfo, InputShape("p", T, B, "prefill"),
+                capacity=capacity)[0]
+            return fn(params, {"tokens": tokens})
+
+    def decode(params, cache, tok, pos):
+        with minfo.mesh:
+            fn = steps.make_decode_step(
+                cfg, minfo, InputShape("d", capacity, tok.shape[0], "decode"))[0]
+            return fn(params, cache, tok, pos)
+    return prefill, decode
+
+
+@pytest.mark.parametrize("path", [_api_path, _steps_path],
+                         ids=["api", "steps"])
 @pytest.mark.parametrize("arch", ["smollm_360m", "gemma2_2b", "mamba2_1p3b",
                                   "zamba2_1p2b", "mixtral_8x22b",
                                   "granite_20b"])
-def test_decode_matches_prefill(arch, rng):
-    """Prefilling [t0..tN] must equal prefilling [t0..tN-1] then decoding tN."""
+def test_decode_matches_prefill(arch, path, rng):
+    """Prefilling [t0..tN] must equal prefilling [t0..tN-2] then decoding
+    tN-1 and tN, in the logits and in the cache written, both on one device
+    and through the step builders: either way the cache is written by
+    ``distributed.cache_update.write``."""
     cfg = get_config(arch, tiny=True)
     params = api.init_params(rng, cfg)
     T = 32
     tokens = jax.random.randint(rng, (1, T), 0, cfg.vocab_size, jnp.int32)
+    prefill, decode = path(cfg, T + 4)
 
-    full_logits, _ = api.prefill(params, {"tokens": tokens}, cfg, capacity=T + 4)
-    part_logits, cache = api.prefill(params, {"tokens": tokens[:, :-1]}, cfg,
-                                     capacity=T + 4)
-    step_logits, _ = api.decode_step(params, cache, tokens[:, -1],
-                                     jnp.asarray(T - 1, jnp.int32), cfg)
+    full_logits, full_cache = prefill(params, tokens)
+    _, cache = prefill(params, tokens[:, :-2])
+    for pos in (T - 2, T - 1):
+        step_logits, cache = decode(params, cache, tokens[:, pos],
+                                    jnp.asarray(pos, jnp.int32))
+    for want, got in zip(jax.tree.leaves(full_cache), jax.tree.leaves(cache)):
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   atol=2e-1, rtol=2e-1)
     np.testing.assert_allclose(np.asarray(step_logits, np.float32),
                                np.asarray(full_logits, np.float32),
                                atol=2e-1, rtol=2e-1)
